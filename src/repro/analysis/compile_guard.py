@@ -5,15 +5,18 @@ dispatch: ring restaging, pagestore residency swaps and fault plans all
 reuse the single warmed ``engine_run_chunk_admit`` executable, so the
 host never pays a compile on the critical path.  ``CompileGuard`` turns
 that claim into a machine check by hooking jax's cache-miss path
-(``backend_compile``) and recording the name of every HLO module that
-actually reaches the backend compiler.
+(``jax._src.compiler.backend_compile_and_load``) and recording the name
+of every HLO module that actually reaches the backend compiler.
 
 Cache *hits* never reach this hook, so a guarded region that triggers
 no compiles records nothing -- which is exactly the property we want to
-assert.  Names are per-module symbols like ``jit_engine_run_chunk_admit``,
-so callers filter with ``count("engine_run_chunk_admit")`` and are not
-confused by unrelated tiny compiles (``jit_convert_element_type`` ...)
-or by the pagestore's pow2-padded ``_scatter_frames`` variants.
+assert.  That includes hits in JAX's persistent compilation cache: a
+process that turned it on (``repro.launch.compile_cache``) counts only
+modules the cache did not already hold.  Names are per-module symbols
+like ``jit_engine_run_chunk_admit``, so callers filter with
+``count("engine_run_chunk_admit")`` and are not confused by unrelated
+tiny compiles (``jit_convert_element_type`` ...) or by the pagestore's
+pow2-padded ``_scatter_frames`` variants.
 
 Usage::
 
@@ -29,16 +32,6 @@ or enforcing inline::
 from __future__ import annotations
 
 from typing import Optional
-
-
-def _compile_hook_target():
-    """Locate jax's backend_compile across the versions we support."""
-    import jax  # noqa: F401  - ensures _src is importable
-    from jax._src import compiler as _compiler
-    if hasattr(_compiler, "backend_compile"):
-        return _compiler, "backend_compile"
-    from jax._src import dispatch as _dispatch  # pragma: no cover
-    return _dispatch, "backend_compile"  # pragma: no cover
 
 
 def _module_name(module) -> str:
@@ -72,8 +65,6 @@ class CompileGuard:
         self.match = match
         self.max_compiles = max_compiles
         self.names: list = []
-        self._holder = None
-        self._attr = None
         self._orig = None
 
     # -- queries -----------------------------------------------------------
@@ -89,21 +80,20 @@ class CompileGuard:
 
     # -- context protocol --------------------------------------------------
     def __enter__(self):
-        holder, attr = _compile_hook_target()
-        self._holder, self._attr = holder, attr
-        self._orig = getattr(holder, attr)
-        orig = self._orig
+        from jax._src import compiler
+        self._orig = orig = compiler.backend_compile_and_load
         names = self.names
 
-        def _recording_backend_compile(backend, module, *args, **kwargs):
+        def _recording_compile(backend, module, *args, **kwargs):
             names.append(_module_name(module))
             return orig(backend, module, *args, **kwargs)
 
-        setattr(holder, attr, _recording_backend_compile)
+        compiler.backend_compile_and_load = _recording_compile
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        setattr(self._holder, self._attr, self._orig)
+        from jax._src import compiler
+        compiler.backend_compile_and_load = self._orig
         if exc_type is None and self.max_compiles is not None:
             n = self.count(self.match)
             if n > self.max_compiles:

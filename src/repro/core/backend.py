@@ -76,7 +76,6 @@ class KernelBackend:
 
     mode         : see :data:`MODES`; resolved lazily so a config built on
                    the host applies to whatever backend jit runs on.
-    sort_block_b : rows per Pallas grid step of the bitonic network.
     coalesce_qb  : per-page query-tile width for ``item_distances``:
                    up to this many same-page assignments share one page
                    read. 0 keeps the per-item path (one grid step per
@@ -88,7 +87,6 @@ class KernelBackend:
     """
 
     mode: str = "auto"
-    sort_block_b: int = 1
     coalesce_qb: int = 8
     coalesce_min_reuse: float = 2.0
 
@@ -124,8 +122,7 @@ class KernelBackend:
             return bitonic_sort_ref(dists, ids, *payload)
         packed = tuple(p.astype(jnp.int32) if p.dtype == jnp.bool_ else p
                        for p in payload)
-        out = sort_op(dists, ids, *packed, mode=mode,
-                      block_b=self.sort_block_b)
+        out = sort_op(dists, ids, *packed, mode=mode)
         restored = tuple(o.astype(p.dtype) for o, p in zip(out[2:], payload))
         return (out[0], out[1]) + restored
 
@@ -153,8 +150,7 @@ class KernelBackend:
         packed_b = tuple(p.astype(jnp.int32) if p.dtype == jnp.bool_ else p
                          for p in pay_b)
         out = merge_sorted_op(d_a, i_a, d_b, i_b, pay_a=packed_a,
-                              pay_b=packed_b, mode=mode,
-                              block_b=self.sort_block_b)
+                              pay_b=packed_b, mode=mode)
         restored = tuple(o.astype(p.dtype) for o, p in zip(out[2:], pay_a))
         return (out[0], out[1]) + restored
 

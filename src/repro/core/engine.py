@@ -1303,13 +1303,8 @@ def engine_run_chunk_admit(consts, state: EngineState, queries, spec_state,
 
 
 def _shard_map_fn(fn, mesh, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    # jax < 0.6: shard_map lives in experimental, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_stepper(params: EngineParams, geom: EngineGeom, mesh=None,

@@ -155,6 +155,7 @@ def squared_dists(queries, qq, vecs, vnorm,
     backend = backend or _JNP
     if backend.inline:
         qv = jnp.einsum("qd,qmd->qm", queries, vecs,
+                        precision=jax.lax.Precision.HIGHEST,
                         preferred_element_type=jnp.float32)
         return qq[:, None] - 2.0 * qv + vnorm
     Q = queries.shape[0]

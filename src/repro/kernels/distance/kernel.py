@@ -23,17 +23,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_ROWS = 8   # f32 sublane tile: vnorm rows per block
+
 
 def _distance_kernel(page_ids_ref, q_ref, qq_ref, db_ref, vnorm_ref, o_ref):
-    del page_ids_ref  # only consumed by the index_maps
+    i = pl.program_id(0)
     q = q_ref[0]                      # (QB, d)
     page = db_ref[0]                  # (P, d)
     qv = jax.lax.dot_general(
         q, page, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)          # (QB, P)
-    o_ref[0] = (qq_ref[0][:, None].astype(jnp.float32)
+    # vnorm arrives as the 8-row (sublane-tile) block holding this
+    # step's page; pick the page's own row
+    vn = vnorm_ref[pl.ds(page_ids_ref[i] % _ROWS, 1), :]    # (1, P)
+    o_ref[0] = (qq_ref[0].astype(jnp.float32)
                 - 2.0 * qv
-                + vnorm_ref[0][None, :].astype(jnp.float32))
+                + vn.astype(jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -48,6 +54,10 @@ def paged_distances(page_ids: jax.Array, queries: jax.Array, qq: jax.Array,
     db       : (NP, P, d)  f32/bf16  shard vector store (paged)
     vnorm    : (NP, P)     f32  per-vector self dot
     returns  : (T, QB, P)  f32
+
+    Every block's last two dimensions are either whole array dimensions
+    or multiples of the (8, 128) tile, as compiled Mosaic requires: qq
+    rides as (T, QB, 1) columns and vnorm as 8-row blocks.
     """
     T, QB, d = queries.shape
     NP, P, _ = db.shape
@@ -56,9 +66,9 @@ def paged_distances(page_ids: jax.Array, queries: jax.Array, qq: jax.Array,
         grid=(T,),
         in_specs=[
             pl.BlockSpec((1, QB, d), lambda i, pid: (i, 0, 0)),
-            pl.BlockSpec((1, QB), lambda i, pid: (i, 0)),
+            pl.BlockSpec((1, QB, 1), lambda i, pid: (i, 0, 0)),
             pl.BlockSpec((1, P, d), lambda i, pid: (pid[i], 0, 0)),
-            pl.BlockSpec((1, P), lambda i, pid: (pid[i], 0)),
+            pl.BlockSpec((_ROWS, P), lambda i, pid: (pid[i] // _ROWS, 0)),
         ],
         out_specs=pl.BlockSpec((1, QB, P), lambda i, pid: (i, 0, 0)),
     )
@@ -67,4 +77,4 @@ def paged_distances(page_ids: jax.Array, queries: jax.Array, qq: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, QB, P), jnp.float32),
         interpret=interpret,
-    )(page_ids, queries, qq, db, vnorm)
+    )(page_ids, queries, qq[:, :, None], db, vnorm)

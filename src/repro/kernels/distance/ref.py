@@ -13,6 +13,7 @@ def paged_distances_ref(page_ids: jax.Array, queries: jax.Array,
     pages = db[page_ids].astype(jnp.float32)        # (T, P, d)
     q = queries.astype(jnp.float32)
     qv = jnp.einsum("tqd,tpd->tqp", q, pages,
+                    precision=jax.lax.Precision.HIGHEST,
                     preferred_element_type=jnp.float32)
     return (qq[:, :, None].astype(jnp.float32)
             - 2.0 * qv

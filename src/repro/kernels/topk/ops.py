@@ -25,7 +25,7 @@ def _on_tpu() -> bool:
 
 
 def sort_op(dists: jax.Array, ids: jax.Array, *payload: jax.Array,
-            mode: str = "auto", block_b: int = 1):
+            mode: str = "auto"):
     """Lexicographic sort rows of (dists, ids); pads M to a power of two.
 
     Payload lanes (same (B, M) shape, i32/f32) ride along unsorted-key;
@@ -49,7 +49,7 @@ def sort_op(dists: jax.Array, ids: jax.Array, *payload: jax.Array,
         out = bitonic_sort_ref(dists, ids, *payload)
     else:
         out = bitonic_sort(dists, ids, *payload,
-                           interpret=(mode == "interpret"), block_b=block_b)
+                           interpret=(mode == "interpret"))
     return tuple(x[:, :M] for x in out)
 
 
@@ -61,7 +61,7 @@ def topk_op(dists: jax.Array, ids: jax.Array, k: int, mode: str = "auto"):
 def merge_sorted_op(d_a: jax.Array, i_a: jax.Array,
                     d_b: jax.Array, i_b: jax.Array,
                     pay_a: tuple = (), pay_b: tuple = (),
-                    mode: str = "auto", block_b: int = 1):
+                    mode: str = "auto"):
     """Merge two per-row ascending (dist, id)-sorted lists into one.
 
     d_a/i_a : (B, LA) sorted rows (e.g. the candidate list)
@@ -96,6 +96,5 @@ def merge_sorted_op(d_a: jax.Array, i_a: jax.Array,
     if mode == "ref":
         out = bitonic_merge_ref(d, i, *pay)
     else:
-        out = bitonic_merge(d, i, *pay, interpret=(mode == "interpret"),
-                            block_b=block_b)
+        out = bitonic_merge(d, i, *pay, interpret=(mode == "interpret"))
     return tuple(x[:, :la + lb] for x in out)

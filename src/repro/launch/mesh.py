@@ -2,25 +2,13 @@
 jax device state (required so smoke tests/benches see a single device)."""
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - older jax has no axis types
-    AxisType = None
-
-_HAS_AXIS_TYPES = (AxisType is not None
-                   and "axis_types" in inspect.signature(
-                       jax.make_mesh).parameters)
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if _HAS_AXIS_TYPES:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

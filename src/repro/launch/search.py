@@ -12,6 +12,7 @@ recall@k / QPS / locality stats.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
 
@@ -23,6 +24,7 @@ from repro.core.luncsr import Geometry, LUNCSR, pack_index
 from repro.core.ref_search import SearchParams
 from repro.core.reorder import apply_reordering, degree_ascending_bfs
 from repro.data.vectors import PAPER_DATASETS, VectorDataset
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_index(db: np.ndarray, *, shards: int, page_size: int, r: int,
@@ -38,7 +40,7 @@ def build_index(db: np.ndarray, *, shards: int, page_size: int, r: int,
     return db, pack_index(idx, max_degree=r)
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="sift-1b",
                     choices=sorted(PAPER_DATASETS) + ["tiny"])
@@ -158,14 +160,32 @@ def main(argv=None):
                          "known down — degraded fusion over the rest")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+@dataclasses.dataclass
+class BuiltIndex:
+    """What :func:`build` makes from the CLI arguments: the dataset's
+    name, the queries, the (reordered) vectors, the packed index, and —
+    for routed or live serving — the routed or live index."""
+
+    name: str
+    queries: np.ndarray
+    db: np.ndarray
+    packed: object
+    routed: object
+    live: object
+    build_s: float
+
+
+def build(args: argparse.Namespace) -> BuiltIndex:
+    """Materialize the dataset and build the index the arguments ask for
+    (host-side graph build; its seconds are ``build_s``)."""
     if args.dataset == "tiny":
         ds = VectorDataset("tiny", n=args.n or 2048, dim=64, clusters=16)
     else:
         ds = PAPER_DATASETS[args.dataset]
         if args.n:
-            import dataclasses
             ds = dataclasses.replace(ds, n=args.n)
     db0 = ds.materialize()
     queries = ds.queries(args.queries, seed=args.seed + 1)
@@ -216,7 +236,17 @@ def main(argv=None):
             seed=args.seed)
         print(f"index built in {time.time() - t0:.1f}s "
               f"(reorder={args.reorder}, spec={args.spec})")
+    return BuiltIndex(ds.name, queries, db, packed, routed, live,
+                      time.time() - t0)
 
+
+def serve(args: argparse.Namespace, built: BuiltIndex):
+    """Run the search the arguments describe over a built index.
+
+    Returns ``(report, ids)``: the JSON-able report :func:`main` prints
+    and the (queries, k) result ids."""
+    queries, db, packed = built.queries, built.db, built.packed
+    routed, live = built.routed, built.live
     consts, geom, entry = pack_for_engine(packed)
     sp = SearchParams(L=args.L, W=args.W, k=args.k)
     S = args.shards
@@ -235,39 +265,27 @@ def main(argv=None):
             corrupt_mode=args.corrupt_mode, seed=args.seed)
         if (args.deadline_rounds or args.nan_guard or faults is not None
                 or live is not None):
-            import dataclasses
             params = dataclasses.replace(
                 params, deadline_rounds=args.deadline_rounds,
                 guard_nonfinite=args.nan_guard, faults=faults,
                 delta_cap=args.delta_cap)
         down = ([int(s) for s in args.down_shards.split(",")]
                 if args.down_shards else None)
-        res = {
-            "dataset": ds.name, "mode": "stream",
-            "kernel_mode": args.kernel_mode, "n": int(db.shape[0]),
-            **stream_report(consts, geom, params, entry, db,
-                            queries[:args.queries], slots=args.slots,
-                            arrival_rate=args.arrival_rate,
-                            seed=args.seed + 2,
-                            dynamic_spec=args.spec_dynamic,
-                            round_chunk=args.round_chunk,
-                            injit_admit={"auto": None, "on": True,
-                                         "off": False}[args.injit_admit],
-                            routed=routed, topr=args.topr,
-                            leg_L=args.leg_L or None,
-                            spec_page_w=args.spec_page_w,
-                            ring_capacity=args.ring,
-                            overload=args.overload, down_shards=down,
-                            device_pages=args.device_pages,
-                            prefetch=args.prefetch,
-                            prefetch_page_w=args.prefetch_page_w,
-                            live=live),
-        }
-        print(json.dumps(res, indent=1))
-        if args.out:
-            with open(args.out, "w") as f:
-                json.dump(res, f, indent=1)
-        return 0
+        report, ids = stream_report(
+            consts, geom, params, entry, db, queries[:args.queries],
+            slots=args.slots, arrival_rate=args.arrival_rate,
+            seed=args.seed + 2, dynamic_spec=args.spec_dynamic,
+            round_chunk=args.round_chunk,
+            injit_admit={"auto": None, "on": True,
+                         "off": False}[args.injit_admit],
+            routed=routed, topr=args.topr, leg_L=args.leg_L or None,
+            spec_page_w=args.spec_page_w, ring_capacity=args.ring,
+            overload=args.overload, down_shards=down,
+            device_pages=args.device_pages, prefetch=args.prefetch,
+            prefetch_page_w=args.prefetch_page_w, live=live)
+        return {"dataset": built.name, "mode": "stream",
+                "kernel_mode": args.kernel_mode, "n": int(db.shape[0]),
+                **report}, ids
 
     params = EngineParams.lossless(
         sp, -(-args.queries // args.shards), args.degree,
@@ -283,7 +301,7 @@ def main(argv=None):
     true_ids, _ = brute_force_topk(db, queries[:qs], args.k)
     rec = recall_at_k(ids, true_ids)
     res = {
-        "dataset": ds.name, "kernel_mode": args.kernel_mode,
+        "dataset": built.name, "kernel_mode": args.kernel_mode,
         "coalesce_qb": args.coalesce_qb,
         "n": int(db.shape[0]), "queries": qs,
         "recall@k": round(float(rec), 4), "qps": round(qs / dt, 1),
@@ -292,6 +310,13 @@ def main(argv=None):
         "pages_unique": int(np.asarray(stats["pages_unique"]).sum()),
         "items_recv": int(np.asarray(stats["items_recv"]).sum()),
     }
+    return res, ids
+
+
+def main(argv=None):
+    enable_compile_cache()
+    args = parse_args(argv)
+    res, _ = serve(args, build(args))
     print(json.dumps(res, indent=1))
     if args.out:
         with open(args.out, "w") as f:

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 
 
@@ -95,6 +96,7 @@ def soft_prompt_from_retrieval(cfg, queries: np.ndarray, k: int = 4,
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

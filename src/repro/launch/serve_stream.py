@@ -27,6 +27,7 @@ from repro.core.ref_search import SearchParams
 from repro.core.scheduler import poisson_arrivals, stream_search
 from repro.data.vectors import PAPER_DATASETS, VectorDataset
 from repro.ft.inject import parse_fault_args
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.search import build_index
 
 
@@ -99,10 +100,11 @@ def stream_report(consts, geom, params, entry, db, queries, *, slots,
                   routed=None, topr=0, leg_L=None,
                   spec_page_w=0.0, ring_capacity=0, overload="block",
                   down_shards=None, device_pages=0, prefetch=True,
-                  prefetch_page_w=1.0, live=None) -> dict:
+                  prefetch_page_w=1.0, live=None):
     """Run one streaming session and build the serving report shared by
     the `search --stream` and `serve_stream` CLIs: Poisson arrivals ->
     scheduler -> recall vs brute force + stream_summary metrics.
+    Returns ``(report, ids)``, ``ids`` the (queries, k) results.
 
     With ``routed`` (a :class:`repro.core.router.RoutedIndex`) and
     ``topr`` > 0, queries go through the two-tier path: the coarse
@@ -191,10 +193,11 @@ def stream_report(consts, geom, params, entry, db, queries, *, slots,
         # *resolved* admission path, not a re-derivation of the flag
         "recall@k": round(float(recall_at_k(ids, true_ids)), 4),
         **stream_summary(st),
-    }
+    }, ids
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="tiny",
                     choices=sorted(PAPER_DATASETS) + ["tiny"])
@@ -371,27 +374,20 @@ def main(argv=None):
     down = ([int(s) for s in args.down_shards.split(",")]
             if args.down_shards else None)
 
-    res = {
-        "dataset": ds.name, "n": int(db.shape[0]),
-        "kernel_mode": args.kernel_mode,
-        **stream_report(consts, geom, params, entry, db, queries,
-                        slots=args.slots, arrival_rate=args.arrival_rate,
-                        seed=args.seed + 2,
-                        dynamic_spec=args.spec_dynamic,
-                        refill=not args.no_refill,
-                        round_chunk=args.round_chunk,
-                        injit_admit={"auto": None, "on": True,
-                                     "off": False}[args.injit_admit],
-                        routed=routed, topr=args.topr,
-                        leg_L=args.leg_L or None,
-                        spec_page_w=args.spec_page_w,
-                        ring_capacity=args.ring, overload=args.overload,
-                        down_shards=down,
-                        device_pages=args.device_pages,
-                        prefetch=args.prefetch,
-                        prefetch_page_w=args.prefetch_page_w,
-                        live=live),
-    }
+    report, _ = stream_report(
+        consts, geom, params, entry, db, queries, slots=args.slots,
+        arrival_rate=args.arrival_rate, seed=args.seed + 2,
+        dynamic_spec=args.spec_dynamic, refill=not args.no_refill,
+        round_chunk=args.round_chunk,
+        injit_admit={"auto": None, "on": True,
+                     "off": False}[args.injit_admit],
+        routed=routed, topr=args.topr, leg_L=args.leg_L or None,
+        spec_page_w=args.spec_page_w, ring_capacity=args.ring,
+        overload=args.overload, down_shards=down,
+        device_pages=args.device_pages, prefetch=args.prefetch,
+        prefetch_page_w=args.prefetch_page_w, live=live)
+    res = {"dataset": ds.name, "n": int(db.shape[0]),
+           "kernel_mode": args.kernel_mode, **report}
     print(json.dumps(res, indent=1))
     if args.out:
         with open(args.out, "w") as f:

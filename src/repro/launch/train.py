@@ -22,6 +22,7 @@ from repro import checkpoint as ckpt
 from repro.configs.registry import get_config, reduced
 from repro.data.pipeline import FrontendPipeline, TokenPipeline
 from repro.ft.restart import run_with_restarts
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.models.sharding import make_rules
 from repro.optim.adamw import OptConfig, init_opt
@@ -58,6 +59,7 @@ def build(args):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
