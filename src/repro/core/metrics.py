@@ -70,9 +70,10 @@ def stream_summary(stats) -> dict:
     """Aggregate a scheduler StreamStats into the serving report:
     occupancy, per-query latency percentiles (rounds + wall), round-
     normalized throughput, sustained wall QPS and the host-sync model
-    (engine_run_chunk dispatches, one-time compile seconds — ``wall_s``
-    and per-query wall latency exclude the compile, which is reported
-    separately). Clock accounting: ``total_rounds`` counts engine
+    (engine_run_chunk dispatches, warm-up seconds — ``wall_s`` and
+    per-query wall latency exclude the warm-up dispatch, a compile only
+    the first time a shape is seen, which is reported separately).
+    Clock accounting: ``total_rounds`` counts engine
     (busy) rounds, ``idle_rounds`` the empty-pool gaps the scheduler
     skipped over; ``occupancy`` and ``queries_per_round`` are
     normalized over the *full* serving clock (busy + idle) so sparse

@@ -92,6 +92,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.core.engine import (EngineGeom, EngineParams, EngineStepper,
                                engine_retire_live, make_stepper,
                                spec_update)
@@ -289,7 +290,9 @@ class StreamStats:
     spec_trace: list          # mean spec_w over live rows, each round
     wall_s: float             # steady-state wall clock (excl. compile)
     host_dispatches: int = 0  # engine_run_chunk launches (host syncs)
-    compile_s: float = 0.0    # one-time stepper warmup/compile seconds
+    compile_s: float = 0.0    # this call's warm-up dispatch, seconds (a
+                              # compile only the first time a shape is
+                              # seen; the search.warmup span)
     idle_rounds: int = 0      # serving-clock rounds the pool sat empty
                               # waiting for an arrival (no engine work)
     injit_admit: bool = False  # admission path the run actually used
@@ -636,7 +639,7 @@ class StreamScheduler:
         steady state, not the one-time jit compile (mirrors serve.py's
         prefill/decode warmup). Returns the seconds spent."""
         S, Qs = self.S, self.num_slots
-        t0 = time.time()
+        t0 = time.perf_counter()
         spec_state, cfg, dyn = self._spec_inputs((S, Qs))
         if pend is not None:
             # compile on the real staged queue (its shape fixes the
@@ -661,7 +664,7 @@ class StreamScheduler:
                                                *self.entry)
                 jax.block_until_ready(astate.done)
             jax.block_until_ready((out[0].done, out[13], ids, dists))
-            return time.time() - t0
+            return time.perf_counter() - t0
         zmask = jnp.zeros((S, Qs), bool)
         wstate, wq = self.stepper.admit(state, qbuf, zmask, qbuf,
                                         *self.entry)
@@ -671,13 +674,18 @@ class StreamScheduler:
                                      cfg, 1, False, dynamic=dyn)
         ids, dists, _ = self._retire(wstate, wq)
         jax.block_until_ready((out[0].done, ids, dists))
-        return time.time() - t0
+        return time.perf_counter() - t0
 
     def run(self, queries: np.ndarray,
             arrivals: Optional[np.ndarray] = None,
-            target_shards: Optional[np.ndarray] = None) -> StreamStats:
+            target_shards: Optional[np.ndarray] = None,
+            phases: Optional[spans.Phases] = None) -> StreamStats:
         """Serve ``queries`` (N, d); ``arrivals`` are arrival rounds
         (default: all at round 0). Returns per-query results + metrics.
+
+        ``phases`` is the caller's span sequence of the call, its
+        ``search.setup`` open (see :mod:`repro.core.spans`); without it
+        the run writes a ``search.call`` of its own.
 
         ``target_shards`` (N,) switches to **routed admission** (needs
         ``routed=True`` at construction): row i may only be seated in
@@ -686,6 +694,12 @@ class StreamScheduler:
         routed work stays parked — the two-tier serving discipline
         (``routed_stream_search`` fans queries into per-shard legs and
         fuses their top-k)."""
+        if phases is None:
+            call = spans.next_call()
+            with spans.span(spans.CALL, call=call), \
+                    spans.Phases(call) as phases:
+                phases(spans.SETUP)
+                return self.run(queries, arrivals, target_shards, phases)
         queries = np.asarray(queries, np.float32)
         N, d = queries.shape
         arrivals = (np.zeros(N, np.int64) if arrivals is None
@@ -753,7 +767,8 @@ class StreamScheduler:
             # so one warmup compile covers every dispatch
             warm_pend = (jnp.zeros((ring, d), jnp.float32),
                          jnp.full((ring,), NEVER, jnp.int32))
-        compile_s = self._warmup(state, qbuf, warm_pend)
+        with spans.span(spans.WARMUP, call=phases.call):
+            compile_s = self._warmup(state, qbuf, warm_pend)
         owner = np.full((S, Qs), INVALID, np.int64)   # slot -> qid
         admit_t = np.zeros((S, Qs), np.int64)
         admit_wall = np.zeros((S, Qs), np.float64)
@@ -774,7 +789,7 @@ class StreamScheduler:
         results: list[QueryResult] = []
         occ_trace: list[int] = []
         spec_trace: list[float] = []
-        t0 = time.time()
+        t0 = time.perf_counter()
 
         def next_arrival():
             """Earliest arrival round among unadmitted queries (None
@@ -791,6 +806,8 @@ class StreamScheduler:
             return int(arrivals[order[next_q]]) if next_q < N else None
 
         while retired + len(shed_qids) < N:
+            # an idle-clock jump keeps this dispatch's stage span open
+            phases(spans.STAGE, chunk=dispatches)
             if self.live is not None and self.live.due(t):
                 # -- live-index boundary: apply every scheduled insert/
                 # delete due by the serving clock; a triggered reindex
@@ -809,7 +826,7 @@ class StreamScheduler:
                 # own free rows from its own arrived queue
                 mask = np.zeros((S, Qs), bool)
                 new_q = np.zeros((S, Qs, d), np.float32)
-                now_wall = time.time()
+                now_wall = time.perf_counter()
                 for s in range(S):
                     free_rows = np.flatnonzero(owner[s] == INVALID)
                     i = 0
@@ -845,7 +862,7 @@ class StreamScheduler:
                 if staged:
                     mask = np.zeros((S, Qs), bool)
                     new_q = np.zeros((S, Qs, d), np.float32)
-                    now_wall = time.time()
+                    now_wall = time.perf_counter()
                     for (s, r), qid in zip(free[:len(staged)], staged):
                         mask[s, r] = True
                         new_q[s, r] = queries[qid]
@@ -878,7 +895,7 @@ class StreamScheduler:
                 # no stop-on-finish — freed slots are reseated in-jit
                 # at the exact boundary, and the admit/evict traces let
                 # the host replay the accounting afterwards
-                launch_wall = time.time()
+                launch_wall = time.perf_counter()
                 if ring:
                     # -- bounded ring: slide the window forward (refill
                     # in arrival order while seats are free), then — if
@@ -909,17 +926,20 @@ class StreamScheduler:
                 else:
                     cursor = (jnp.asarray(next_qs, jnp.int32) if routed
                               else next_q)
+                phases(spans.DISPATCH, chunk=dispatches)
                 (state, qbuf, spec_state, steps, live_cnt, width_sum,
                  admit_qidx, ret_i, ret_d, ret_rounds, ret_ndist,
                  ret_age, ret_trunc, cur) = \
                     self.stepper.run_chunk_admit(
                         self.consts, state, qbuf, spec_state, cfg, K,
                         pend, cursor, t, self.entry, dynamic=dyn)
-                dispatches += 1
+                phases(spans.SYNC, chunk=dispatches)
                 # the chunk boundary's one sync: everything else below
                 # transfers lazily (and batched) only if needed
                 steps = int(jax.device_get(steps))
-                now_wall = time.time()
+                phases(spans.ACCOUNT, chunk=dispatches)
+                dispatches += 1
+                now_wall = time.perf_counter()
                 admit_qidx = jax.device_get(admit_qidx)[:steps]
                 if admit_qidx.size and (admit_qidx >= 0).any():
                     # a seat happened: fetch all six eviction-capture
@@ -1011,12 +1031,15 @@ class StreamScheduler:
                         budget = max(1, min(K, na - t))
                     else:
                         stop_on_finish = na <= t + K
+                phases(spans.DISPATCH, chunk=dispatches)
                 state, spec_state, steps, live_cnt, width_sum = \
                     self.stepper.run_chunk(self.consts, state, qbuf,
                                            spec_state, cfg, budget,
                                            stop_on_finish, dynamic=dyn)
-                dispatches += 1
+                phases(spans.SYNC, chunk=dispatches)
                 steps = int(jax.device_get(steps))    # host sync point
+                phases(spans.ACCOUNT, chunk=dispatches)
+                dispatches += 1
             t += steps
             stepped += steps
             if self.pagestore is not None and steps:
@@ -1080,7 +1103,7 @@ class StreamScheduler:
             if fin.any():
                 out_i, out_d, _ = self._retire(state, qbuf)
                 out_i, out_d = jax.device_get((out_i, out_d))
-                now_wall = time.time()
+                now_wall = time.perf_counter()
                 for s, r in np.argwhere(fin):
                     # exact even when the finish was mid-chunk: the row
                     # aged `age` consecutive serving rounds from
@@ -1109,6 +1132,7 @@ class StreamScheduler:
                     rounds_base[s, r] = 0
                 retired += int(fin.sum())
 
+        phases(spans.FINISH)
         # end-of-session counters: one transfer for the whole summary
         (pages_unique, items_recv, props_sent, drops_b,
          quarantined) = jax.device_get(
@@ -1122,7 +1146,7 @@ class StreamScheduler:
             items_recv=int(items_recv.sum()),
             props_sent=int(props_sent.sum()),
             drops_b=int(drops_b.sum()),
-            spec_trace=spec_trace, wall_s=time.time() - t0,
+            spec_trace=spec_trace, wall_s=time.perf_counter() - t0,
             host_dispatches=dispatches, compile_s=compile_s,
             idle_rounds=idle, injit_admit=self.injit_admit,
             items_by_shard=[int(x) for x in np.ravel(items_recv)],
@@ -1200,24 +1224,29 @@ def stream_search(consts, geom, params, entry, queries,
     shed by the overload policy keeps its INVALID/0 row in the output
     (check ``stats.shed`` / absence from ``stats.results``). With
     ``live`` the returned ids are external ids (stable across epoch
-    swaps; identical to internal ids in a zero-churn session)."""
-    ctrl = _make_controller(params, geom, dynamic_spec, spec_page_w)
-    sched = StreamScheduler(consts, geom, params, entry,
-                            num_slots=num_slots, mesh=mesh,
-                            controller=ctrl, refill=refill,
-                            round_chunk=round_chunk,
-                            injit_admit=injit_admit,
-                            ring_capacity=ring_capacity,
-                            overload=overload, pagestore=pagestore,
-                            live=live)
-    stats = sched.run(queries, arrivals)
-    k = params.search.k
-    n = np.asarray(queries).shape[0]
-    ids = np.full((n, k), INVALID, np.int32)
-    dists = np.zeros((n, k), np.float32)
-    for r in stats.results:
-        ids[r.qid] = r.ids
-        dists[r.qid] = r.dists
+    swaps; identical to internal ids in a zero-churn session).
+
+    The call writes the host spans of :mod:`repro.core.spans`."""
+    call = spans.next_call()
+    with spans.span(spans.CALL, call=call), spans.Phases(call) as phases:
+        phases(spans.SETUP)
+        ctrl = _make_controller(params, geom, dynamic_spec, spec_page_w)
+        sched = StreamScheduler(consts, geom, params, entry,
+                                num_slots=num_slots, mesh=mesh,
+                                controller=ctrl, refill=refill,
+                                round_chunk=round_chunk,
+                                injit_admit=injit_admit,
+                                ring_capacity=ring_capacity,
+                                overload=overload, pagestore=pagestore,
+                                live=live)
+        stats = sched.run(queries, arrivals, phases=phases)
+        k = params.search.k
+        n = np.asarray(queries).shape[0]
+        ids = np.full((n, k), INVALID, np.int32)
+        dists = np.zeros((n, k), np.float32)
+        for r in stats.results:
+            ids[r.qid] = r.ids
+            dists[r.qid] = r.dists
     return ids, dists, stats
 
 
