@@ -201,6 +201,14 @@ class KernelBackend:
             return coalesce_num_tiles(items, npages, self.coalesce_qb)
         return items
 
+    def distance_lanes(self, items: int, npages: int) -> int:
+        """Static query lanes ``item_distances`` computes for ``items``
+        assignments over ``npages`` pages: tiles x tile width when the
+        coalesced tiles engage, else one lane per assignment."""
+        if self.inline or not self.coalesce_active(items, npages):
+            return items
+        return self.distance_grid_steps(items, npages) * self.coalesce_qb
+
     def coalesce_occupancy(self, items: int, npages: int) -> float:
         """Fraction of coalesced-tile query lanes holding a real
         assignment: ``items / (grid_steps * qb)``. 1.0 means every page

@@ -19,12 +19,31 @@ Searching -> Gathering pipeline:
   merge (Gather + Sort): bloom-insert computed proposals, bitonic-merge
       into candidate lists, refresh termination mask.
 
-Two drivers share the same stage functions bit-for-bit:
+The stage functions ``_fa_select`` / ``_fb_adjacency`` (phase A),
+``_fc_propose`` (phase B's proposals) and ``_fe_merge`` (the merge) are
+shared by two drivers:
 
   * ``search_sim``          — the shard axis is a leading array axis;
                               all_to_all == swapaxes. Runs on one device.
+                              The distance exchange runs over the
+                              round's own proposals: one distance call
+                              over every shard's flat proposal list,
+                              the owner folded into the page key, no
+                              per-destination buckets
+                              (:func:`_fd_distance_flat`).
   * ``search_distributed``  — shard_map over a 1-D "lun" mesh with
-                              lax.all_to_all. Multi-device SPMD.
+                              lax.all_to_all. Multi-device SPMD; real
+                              collectives need fixed per-destination
+                              buffers, so ``_fc_bucket`` /
+                              ``_fd_distance`` / ``_fe_gather`` post
+                              ``capacity_b``-slot buckets (as the sim
+                              driver still does for the tiered store
+                              and the ``gather_vectors`` baseline).
+
+``capacity_b`` bounds what one source may send one owner in a round
+(proposals ranked beyond it are dropped and counted in ``drops_b``) on
+both drivers; on the sim driver over a resident store it sizes no
+buffer.
 
 Equality sim == distributed == single-shard traversal (lossless capacity,
 spec off) is tested in tests/test_engine*.py.
@@ -99,12 +118,16 @@ class EngineGeom:
     def logical_slot(self, vid):
         return self.local_page(vid) * self.page_size + vid % self.page_size
 
-    def phys_page(self, vid, blk_perm):
+    def phys_page(self, vid, blk_perm, owner=None):
+        """Physical page of ``vid`` on its owner: ``blk_perm`` is the
+        owner's (B,) block permutation, or all shards' (S, B) with
+        ``owner`` the per-item shard index."""
         lpage = self.local_page(vid)
         blk = lpage // self.pages_per_block
         pib = lpage % self.pages_per_block
-        blk = jnp.clip(blk, 0, blk_perm.shape[0] - 1)
-        return blk_perm[blk] * self.pages_per_block + pib
+        blk = jnp.clip(blk, 0, blk_perm.shape[-1] - 1)
+        base = blk_perm[blk] if owner is None else blk_perm[owner, blk]
+        return base * self.pages_per_block + pib
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,7 +136,11 @@ class EngineParams:
 
     search: SearchParams
     capacity_a: int                 # phase-A request slots per destination
-    capacity_b: int                 # phase-B assignment slots per destination
+    capacity_b: int                 # proposals one source may send one
+                                    # owner a round (more are dropped,
+                                    # drops_b); sizes the shard_map
+                                    # driver's buckets, no buffer on the
+                                    # sim driver over a resident store
     sort_by_page: bool = True       # dynamic allocating (page-locality stats)
     spec_width: int = 0             # 2nd-order speculative prefetch width
     gather_vectors: bool = False    # baseline: move vectors, not distances
@@ -198,6 +225,10 @@ class EngineState(NamedTuple):
     truncated: jax.Array  # (Qs,) bool — retired by deadline with its
                           # best-so-far top-k, not by convergence
     items_recv: jax.Array    # () items received by this shard's SiN
+    distance_lanes: jax.Array  # () query lanes the distance stage
+                               # computed (tiles x tile width, static per
+                               # round); the sim driver's one stage for
+                               # all shards books its lanes on shard 0
     pages_unique: jax.Array  # () unique page reads (dynamic allocating)
     drops_b: jax.Array       # () phase-B overflow drops at this source
     props_sent: jax.Array    # () accepted proposals sent by this source
@@ -243,7 +274,7 @@ def _init_state(queries, qq, entry_vec, entry_norm, entry_id,
     pz = jnp.zeros((params.store_pages,), bool)
     return EngineState(cand_d, cand_i, cand_e, bloom, z.astype(bool),
                        z, z, z, jnp.full((Qs,), dl, jnp.int32),
-                       z.astype(bool), zs, zs, zs, zs, zs, pz, pz)
+                       z.astype(bool), zs, zs, zs, zs, zs, zs, pz, pz)
 
 
 def _fa_select(state: EngineState, params: EngineParams, geom: EngineGeom):
@@ -283,27 +314,32 @@ def _fb_adjacency(recv, adj, pref, params: EngineParams, geom: EngineGeom):
     return send
 
 
-def _fc_propose(state: EngineState, keep_a, recv_b, queries, qq, spec_w,
-                my_shard, params: EngineParams, geom: EngineGeom):
-    """Build proposals, dedup + bloom-filter, bucket phase-B assignments.
+def _fc_propose(state: EngineState, keep_a, recv_b, spec_w, my_shard,
+                params: EngineParams, geom: EngineGeom):
+    """Build proposals, dedup + bloom-filter, rank them by owner.
 
     ``spec_w`` is the *dynamic* speculation width — a traced i32, scalar
     or per-query (Qs,), in [0, params.spec_width]. Shapes stay static at
     the configured maximum; prefetch columns at or beyond a query's
     width are masked to INVALID, which is bit-identical to running that
     query at the smaller static width (masked proposals never survive
-    dedup/bucketing). The streaming scheduler's controller shrinks each
+    dedup/ranking). The streaming scheduler's controller shrinks each
     query's width as its own hit rate decays, without recompiling.
 
     ``my_shard`` is this shard's index, only read when
     ``params.local_only`` — routed legs drop every proposal owned by
-    another shard *before* ranking/bucketing, so a leg's traversal (and
-    all of its phase-B distance work) stays on its home shard and an
-    idle shard receives nothing. With ``local_only=False`` the mask is
-    never built and the stage is bit-for-bit the fan-out stage.
+    another shard *before* ranking, so a leg's traversal (and all of
+    its phase-D distance work) stays on its home shard and an idle
+    shard receives nothing. With ``local_only=False`` the mask is never
+    built and the stage is bit-for-bit the fan-out stage.
+
+    A proposal is sent (``ok``) when its rank among this source's
+    proposals to the same owner is below ``capacity_b``; the rest are
+    dropped and counted. :func:`_fc_bucket` lays the sent ones out in
+    per-owner buckets for the shard_map exchange.
     """
     sp = params.search
-    Qs = queries.shape[0]
+    Qs = state.done.shape[0]
     W, R = sp.W, geom.max_degree
     nbrs = gather_from_buckets(recv_b["nbrs"], keep_a["dest"],
                                keep_a["rank"], keep_a["valid"],
@@ -322,7 +358,6 @@ def _fc_propose(state: EngineState, keep_a, recv_b, queries, qq, spec_w,
             jnp.asarray(spec_w, jnp.int32), (Qs,))[:, None]
         props = jnp.concatenate(
             [props, jnp.where(keep_col, pr, INVALID)], axis=1)
-    M = props.shape[1]
     valid = props != INVALID
     valid = dedup_in_round(props, valid)
     valid &= ~bloom_query(state.bloom, props)
@@ -337,23 +372,31 @@ def _fc_propose(state: EngineState, keep_a, recv_b, queries, qq, spec_w,
     rank, _ = compute_ranks(dest, flat_valid, geom.num_shards)
     ok = flat_valid & (rank < params.capacity_b)
     drops = (flat_valid & ~ok).sum().astype(jnp.int32)
+    return {"dest": dest, "rank": rank, "ok": ok, "props": props,
+            "valid": valid, "drops": drops}
 
-    qidx = jnp.repeat(jnp.arange(Qs, dtype=jnp.int32), M)
+
+def _fc_bucket(keep_c, queries, qq, params: EngineParams,
+               geom: EngineGeom):
+    """Phase-C send for the bucketed exchange: the sent proposals (and
+    their query payloads) scattered into (S, capacity_b) per-owner
+    buckets."""
+    Qs, M = keep_c["props"].shape
+    dest, rank, ok = keep_c["dest"], keep_c["rank"], keep_c["ok"]
     S, C = geom.num_shards, params.capacity_b
     send = {
-        "vid": scatter_to_buckets(dest, rank, ok, flat_vid, S, C,
-                                  fill=INVALID),
+        "vid": scatter_to_buckets(dest, rank, ok, keep_c["props"].reshape(-1),
+                                  S, C, fill=INVALID),
         "mask": bucket_mask(dest, rank, ok, S, C),
     }
     if not params.gather_vectors:
+        qidx = jnp.repeat(jnp.arange(Qs, dtype=jnp.int32), M)
         qpay = queries[qidx]
         if params.payload_bf16:
             qpay = qpay.astype(jnp.bfloat16)
         send["qvec"] = scatter_to_buckets(dest, rank, ok, qpay, S, C)
         send["qq"] = scatter_to_buckets(dest, rank, ok, qq[qidx], S, C)
-    keep = {"dest": dest, "rank": rank, "ok": ok, "props": props,
-            "valid": valid, "drops": drops}
-    return send, keep
+    return send
 
 
 def _fd_distance(recv, db, vnorm, blk_perm, my_shard,
@@ -437,13 +480,94 @@ def _fd_distance(recv, db, vnorm, blk_perm, my_shard,
     return send, items, uniq
 
 
-def _fe_merge(state: EngineState, keep_a, keep_c, recv_d, items, uniq,
-              queries, qq, page_touch=None, page_miss=None,
-              params: EngineParams = None, geom: EngineGeom = None):
-    """Requester: recover distances, bloom-insert, merge, re-terminate.
+def _fd_distance_flat(keep_c, queries, qq, db, vnorm, blk_perm,
+                      params: EngineParams, geom: EngineGeom):
+    """Phase D of every shard at once (sim driver, resident store): one
+    :meth:`KernelBackend.item_distances` call over the flat
+    (S*Qs*M,) list of all shards' proposals, against the (S*NP, P, d)
+    view of the shard-major store, with the owner folded into the page
+    key (``owner * NP + page``). Query tiles are packed from
+    ``queries[s, q]`` directly; nothing is bucketed.
 
-    Tiered store (``params.store_pages > 0``): the reply's ``"miss"``
-    lane marks assignments whose page was not device-resident. A query
+    ``keep_c`` leaves, ``queries`` (S, Qs, d) and ``qq`` (S, Qs) carry
+    the shard axis. Returns (dist (S, Qs*M) in proposal order, BIG_DIST
+    where not sent; per-owner items (S,) and unique pages (S,), equal
+    to the bucketed :func:`_fd_distance`'s counts; distance lanes (S,),
+    the stage's static count booked on shard 0).
+    """
+    S, Qs, d = queries.shape
+    NP, P = vnorm.shape[1:]
+    M = keep_c["props"].shape[-1]
+    ok = keep_c["ok"].reshape(-1)
+    vid = jnp.clip(keep_c["props"].reshape(-1), 0, geom.n - 1)
+    owner = keep_c["dest"].reshape(-1)         # == geom.owner where ok
+    ppage = jnp.clip(geom.phys_page(vid, blk_perm, owner), 0, NP - 1)
+    gpage = owner * NP + ppage
+    qrow = jnp.repeat(jnp.arange(S * Qs, dtype=jnp.int32), M)
+    qvec = queries.reshape(S * Qs, d)[qrow]
+    if params.payload_bf16:
+        qvec = qvec.astype(jnp.bfloat16)
+    dist = params.backend.item_distances(
+        gpage, vid % geom.page_size, ok, qvec, qq.reshape(-1)[qrow],
+        db.reshape(S * NP, P, d), vnorm.reshape(S * NP, P))
+    if params.faults is not None and params.faults.any_corrupt:
+        bad = ftinject.bad_page_mask(params.faults, ppage, owner)
+        dist = jnp.where(bad & ok, ftinject.corrupt_value(params.faults),
+                         dist)
+    items = ((owner[:, None] == jnp.arange(S, dtype=jnp.int32))
+             & ok[:, None]).sum(axis=0).astype(jnp.int32)
+    touched = jnp.zeros((S * NP,), bool).at[
+        jnp.where(ok, gpage, S * NP)].set(True, mode="drop")
+    uniq = touched.reshape(S, NP).sum(axis=1).astype(jnp.int32)
+    lanes = jnp.zeros((S,), jnp.int32).at[0].set(
+        params.backend.distance_lanes(ok.shape[0], S * NP))
+    return dist.reshape(S, Qs * M), items, uniq, lanes
+
+
+def _fe_gather(recv_d, keep_c, queries, qq, params: EngineParams):
+    """Requester, bucketed exchange: each proposal's reply out of its
+    owner's bucket, in proposal order — (dist (Qs*M,), miss (Qs*M,)
+    bool or None). In the gather_vectors baseline the requester
+    computes the distances from the returned vectors."""
+    dest, rank, ok = keep_c["dest"], keep_c["rank"], keep_c["ok"]
+    C = params.capacity_b
+    if params.gather_vectors:
+        vec = gather_from_buckets(recv_d["vec"], dest, rank, ok, C)
+        vn = gather_from_buckets(recv_d["vn"], dest, rank, ok, C)
+        Qs, M = keep_c["props"].shape
+        qidx = jnp.repeat(jnp.arange(Qs, dtype=jnp.int32), M)
+        qv = jnp.sum(queries[qidx].astype(jnp.float32) * vec, axis=-1)
+        dist = qq[qidx] - 2.0 * qv + vn
+    else:
+        dist = gather_from_buckets(recv_d["dist"], dest, rank, ok, C)
+    miss = (gather_from_buckets(recv_d["miss"], dest, rank, ok, C)
+            if params.store_pages else None)
+    return dist, miss
+
+
+def _bucketed_lanes(params: EngineParams, geom: EngineGeom, npages: int,
+                    Qs: int, M: int) -> int:
+    """Static distance lanes one shard's bucketed phase D computes: its
+    S*capacity_b receive slots through ``item_distances``, or, in the
+    gather_vectors baseline, the requester's Qs*M per-item dots."""
+    if params.gather_vectors:
+        return Qs * M
+    return params.backend.distance_lanes(
+        geom.num_shards * params.capacity_b, npages)
+
+
+def _fe_merge(state: EngineState, keep_a, keep_c, dist, items, uniq,
+              lanes, miss=None, page_touch=None, page_miss=None,
+              params: EngineParams = None, geom: EngineGeom = None):
+    """Requester: bloom-insert, merge, re-terminate.
+
+    ``dist`` holds each proposal's distance in proposal order (Qs*M,),
+    read off the flat phase-D result on the sim driver or out of the
+    reply buckets (:func:`_fe_gather`); ``items`` / ``uniq`` / ``lanes``
+    are this shard's phase-D counts.
+
+    Tiered store (``params.store_pages > 0``): ``miss`` marks
+    assignments whose page was not device-resident. A query
     with any missed assignment **stalls** — its entire round is masked
     exactly like a ``done`` row's (candidates, bloom, rounds, n_dist
     all restored), so next round it re-selects the same frontier and
@@ -453,24 +577,10 @@ def _fe_merge(state: EngineState, keep_a, keep_c, recv_d, items, uniq,
     not). ``page_touch`` / ``page_miss`` are this shard's stage-D
     bitmaps, OR-accumulated into the state for the boundary fetcher.
     """
-    sp = params.search
     Qs, L = state.cand_d.shape
     props = keep_c["props"]                        # (Qs, M)
     M = props.shape[1]
-    ok = keep_c["ok"]
-
-    if params.gather_vectors:
-        vec = gather_from_buckets(recv_d["vec"], keep_c["dest"],
-                                  keep_c["rank"], ok, params.capacity_b)
-        vn = gather_from_buckets(recv_d["vn"], keep_c["dest"],
-                                 keep_c["rank"], ok, params.capacity_b)
-        qidx = jnp.repeat(jnp.arange(Qs, dtype=jnp.int32), M)
-        qv = jnp.sum(queries[qidx].astype(jnp.float32) * vec, axis=-1)
-        dist = qq[qidx] - 2.0 * qv + vn
-    else:
-        dist = gather_from_buckets(recv_d["dist"], keep_c["dest"],
-                                   keep_c["rank"], ok, params.capacity_b)
-    accepted = ok.reshape(Qs, M)
+    accepted = keep_c["ok"].reshape(Qs, M)
     dist = jnp.where(accepted, dist.reshape(Qs, M), BIG_DIST)
     if params.store_pages:
         # any missed page stalls the whole query for the round: mask it
@@ -478,9 +588,7 @@ def _fe_merge(state: EngineState, keep_a, keep_c, recv_d, items, uniq,
         # identical round after the boundary fetch. A live row always
         # has an unexpanded candidate (else it would be done), so a
         # stalled row can never be re-terminated by the done update.
-        missf = gather_from_buckets(recv_d["miss"], keep_c["dest"],
-                                    keep_c["rank"], ok, params.capacity_b)
-        stall = ((missf.reshape(Qs, M) & accepted).any(axis=1)
+        stall = ((miss.reshape(Qs, M) & accepted).any(axis=1)
                  & ~state.done)
         keep = state.done | stall
         acc_eff = accepted & ~stall[:, None]
@@ -515,8 +623,8 @@ def _fe_merge(state: EngineState, keep_a, keep_c, recv_d, items, uniq,
     return EngineState(
         cand_d, cand_i, cand_e, bloom, done, rounds, n_dist,
         state.age, state.deadline, state.truncated,
-        state.items_recv + items, state.pages_unique + uniq,
-        state.drops_b + keep_c["drops"],
+        state.items_recv + items, state.distance_lanes + lanes,
+        state.pages_unique + uniq, state.drops_b + keep_c["drops"],
         state.props_sent + acc_eff.sum().astype(jnp.int32),
         state.quarantined + quar, p_touch, p_miss)
 
@@ -537,22 +645,23 @@ def _round(state, consts, params: EngineParams, geom: EngineGeom, a2a,
         spec_w = jnp.int32(params.spec_width)
     if my_shard is None:
         my_shard = jnp.int32(0)
+    queries, qq = consts["queries"], consts["qq"]
     send_a, keep_a = _fa_select(state, params, geom)
     recv_a = a2a(send_a)
     send_b = _fb_adjacency(recv_a, consts["adj"], consts["pref"],
                            params, geom)
     recv_b = a2a(send_b)
-    send_c, keep_c = _fc_propose(state, keep_a, recv_b, consts["queries"],
-                                 consts["qq"], spec_w, my_shard, params,
-                                 geom)
-    recv_c = a2a(send_c)
+    keep_c = _fc_propose(state, keep_a, recv_b, spec_w, my_shard, params,
+                         geom)
+    recv_c = a2a(_fc_bucket(keep_c, queries, qq, params, geom))
     send_d, items, uniq = _fd_distance(recv_c, consts["db"], consts["vnorm"],
                                        consts["blk_perm"], my_shard, params,
                                        geom)
-    recv_d = a2a(send_d)
-    return _fe_merge(state, keep_a, keep_c, recv_d, items, uniq,
-                     consts["queries"], consts["qq"], params=params,
-                     geom=geom)
+    dist, _ = _fe_gather(a2a(send_d), keep_c, queries, qq, params)
+    lanes = _bucketed_lanes(params, geom, consts["db"].shape[0],
+                            *keep_c["props"].shape)
+    return _fe_merge(state, keep_a, keep_c, dist, items, uniq,
+                     jnp.int32(lanes), params=params, geom=geom)
 
 
 def _finalize(state: EngineState, k: int):
@@ -561,7 +670,9 @@ def _finalize(state: EngineState, k: int):
     out_d = state.cand_d[:, :k]
     stats = {
         "rounds": state.rounds, "n_dist": state.n_dist,
-        "items_recv": state.items_recv, "pages_unique": state.pages_unique,
+        "items_recv": state.items_recv,
+        "distance_lanes": state.distance_lanes,
+        "pages_unique": state.pages_unique,
         "drops_b": state.drops_b, "props_sent": state.props_sent,
         "truncated": state.truncated, "quarantined": state.quarantined,
     }
@@ -617,7 +728,9 @@ def _finalize_live(state: EngineState, queries, tombs, delta_vec,
     out_i = jnp.where(out_i != ID_SENTINEL, out_i, INVALID)
     stats = {
         "rounds": state.rounds, "n_dist": state.n_dist,
-        "items_recv": state.items_recv, "pages_unique": state.pages_unique,
+        "items_recv": state.items_recv,
+        "distance_lanes": state.distance_lanes,
+        "pages_unique": state.pages_unique,
         "drops_b": state.drops_b, "props_sent": state.props_sent,
         "truncated": state.truncated, "quarantined": state.quarantined,
     }
@@ -654,7 +767,16 @@ def _sim_round(state, consts, queries, qq, spec_w, params: EngineParams,
 
     The shard axis leads every array. Shared by the one-shot
     ``search_sim`` while_loop and the streaming stepper's
-    :func:`engine_round`."""
+    :func:`engine_round`.
+
+    ``_fa_select``, ``_fb_adjacency`` and ``_fc_propose`` run per shard
+    as on the shard_map driver. The distance stage then runs over the
+    round's own proposals (:func:`_fd_distance_flat`): one distance call
+    over the (S*Qs*M,) list, with no (S, S, capacity_b) buckets to
+    scatter, tile or gather, and ``_fe_merge`` reads each proposal's
+    distance off its result. The tiered store (``store_pages > 0``) and the
+    ``gather_vectors`` baseline keep the bucketed stages, whose reply
+    lanes (``miss``, raw vectors) the flat stage does not carry."""
 
     def a2a(tree):
         return jax.tree_util.tree_map(lambda x: jnp.swapaxes(x, 0, 1), tree)
@@ -662,17 +784,26 @@ def _sim_round(state, consts, queries, qq, spec_w, params: EngineParams,
     vfa = jax.vmap(functools.partial(_fa_select, params=params, geom=geom))
     vfb = jax.vmap(functools.partial(_fb_adjacency, params=params, geom=geom),
                    in_axes=(0, 0, 0))
-    vfc = jax.vmap(functools.partial(_fc_propose, params=params, geom=geom),
-                   in_axes=(0, 0, 0, 0, 0, 0, 0))
+    vfc = jax.vmap(functools.partial(_fc_propose, params=params, geom=geom))
+    vfe = jax.vmap(functools.partial(_fe_merge, params=params, geom=geom))
 
-    shard_ids = jnp.arange(state.done.shape[0], dtype=jnp.int32)
+    S = state.done.shape[0]
+    shard_ids = jnp.arange(S, dtype=jnp.int32)
     send_a, keep_a = vfa(state)
     recv_a = a2a(send_a)
     send_b = vfb(recv_a, consts["adj"], consts["pref"])
     recv_b = a2a(send_b)
-    send_c, keep_c = vfc(state, keep_a, recv_b, queries, qq, spec_w,
-                         shard_ids)
-    recv_c = a2a(send_c)
+    keep_c = vfc(state, keep_a, recv_b, spec_w, shard_ids)
+    if not (params.store_pages or params.gather_vectors):
+        dist, items, uniq, lanes = _fd_distance_flat(
+            keep_c, queries, qq, consts["db"], consts["vnorm"],
+            consts["blk_perm"], params, geom)
+        return vfe(state, keep_a, keep_c, dist, items, uniq, lanes)
+
+    vbucket = jax.vmap(functools.partial(_fc_bucket, params=params,
+                                         geom=geom))
+    recv_c = a2a(vbucket(keep_c, queries, qq))
+    touch = pmiss = None
     if params.store_pages:
         # tiered store: stage D reads frames through the translation
         # table and returns per-shard touch/miss bitmaps, which the
@@ -680,23 +811,21 @@ def _sim_round(state, consts, queries, qq, spec_w, params: EngineParams,
         vfd = jax.vmap(
             lambda recv, db, vn, bp, ms, tt: _fd_distance(
                 recv, db, vn, bp, ms, params, geom, tt))
-        vfe = jax.vmap(
-            lambda st, ka, kc, rd, it, uq, q, qn, tch, pm: _fe_merge(
-                st, ka, kc, rd, it, uq, q, qn, tch, pm, params, geom))
         send_d, items, uniq, touch, pmiss = vfd(
             recv_c, consts["db"], consts["vnorm"], consts["blk_perm"],
             shard_ids, consts["ttab"])
-        recv_d = a2a(send_d)
-        return vfe(state, keep_a, keep_c, recv_d, items, uniq, queries,
-                   qq, touch, pmiss)
-    vfd = jax.vmap(functools.partial(_fd_distance, params=params, geom=geom),
-                   in_axes=(0, 0, 0, 0, 0))
-    vfe = jax.vmap(functools.partial(_fe_merge, params=params, geom=geom),
-                   in_axes=(0, 0, 0, 0, 0, 0, 0, 0))
-    send_d, items, uniq = vfd(recv_c, consts["db"], consts["vnorm"],
-                              consts["blk_perm"], shard_ids)
-    recv_d = a2a(send_d)
-    return vfe(state, keep_a, keep_c, recv_d, items, uniq, queries, qq)
+    else:
+        vfd = jax.vmap(functools.partial(_fd_distance, params=params,
+                                         geom=geom))
+        send_d, items, uniq = vfd(recv_c, consts["db"], consts["vnorm"],
+                                  consts["blk_perm"], shard_ids)
+    dist, miss = jax.vmap(functools.partial(_fe_gather, params=params))(
+        a2a(send_d), keep_c, queries, qq)
+    lanes = jnp.full((S,), _bucketed_lanes(
+        params, geom, consts["db"].shape[1], *keep_c["props"].shape[1:]),
+        jnp.int32)
+    return vfe(state, keep_a, keep_c, dist, items, uniq, lanes, miss, touch,
+               pmiss)
 
 
 @functools.partial(jax.jit, static_argnames=("params", "geom"))
@@ -915,8 +1044,8 @@ def _admit_rows(state: EngineState, queries, admit_mask, new_q,
         jnp.where(admit_mask, 0, state.age),
         jnp.where(admit_mask, fresh.deadline, state.deadline),
         jnp.where(admit_mask, False, state.truncated),
-        state.items_recv, state.pages_unique, state.drops_b,
-        state.props_sent, state.quarantined,
+        state.items_recv, state.distance_lanes, state.pages_unique,
+        state.drops_b, state.props_sent, state.quarantined,
         state.page_touch, state.page_miss)
     return state, q
 
@@ -933,7 +1062,8 @@ def engine_admit(state: EngineState, queries, admit_mask, new_q,
     flags, bloom, done/rounds/n_dist — is rebuilt from scratch by the
     same ``_init_state`` math as the one-shot drivers, so a reused slot
     is bit-identical to a fresh one. Shard-level cumulative counters
-    (items_recv, pages_unique, drops_b, props_sent) are preserved.
+    (items_recv, distance_lanes, pages_unique, drops_b, props_sent) are
+    preserved.
     Returns the new state and the updated (S, Qs, d) query buffer.
     ``entry_vec`` may be per-shard ((S, d)) as in :func:`engine_init`.
     """

@@ -111,6 +111,7 @@ def stream_summary(stats) -> dict:
         "injit_admit": bool(getattr(stats, "injit_admit", False)),
         "pages_unique": stats.pages_unique,
         "items_recv": stats.items_recv,
+        "distance_lanes": stats.distance_lanes,
         "props_sent": stats.props_sent,
         "drops_b": stats.drops_b,
         "legs": getattr(stats, "legs", 0),

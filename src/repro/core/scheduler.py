@@ -303,6 +303,10 @@ class StreamStats:
     items_by_shard: list = dataclasses.field(default_factory=list)
                               # per-shard items_recv — the routed path's
                               # work-skew/idle-shard evidence
+    distance_lanes: int = 0   # query lanes the distance stage computed
+                              # (tiles x tile width, summed over rounds
+                              # and shards); items_recv / distance_lanes
+                              # is the stage's lane occupancy
     shed: int = 0             # queries rejected by the shed overload
                               # policy (admission ring full at arrival)
     truncated: int = 0        # queries retired incomplete: deadline
@@ -1134,10 +1138,10 @@ class StreamScheduler:
 
         phases(spans.FINISH)
         # end-of-session counters: one transfer for the whole summary
-        (pages_unique, items_recv, props_sent, drops_b,
+        (pages_unique, items_recv, lanes, props_sent, drops_b,
          quarantined) = jax.device_get(
-            (state.pages_unique, state.items_recv, state.props_sent,
-             state.drops_b, state.quarantined))
+            (state.pages_unique, state.items_recv, state.distance_lanes,
+             state.props_sent, state.drops_b, state.quarantined))
         return StreamStats(
             results=results, total_rounds=stepped,
             occupancy=slot_occupancy(occ_trace, S * Qs, stepped + idle),
@@ -1150,6 +1154,7 @@ class StreamScheduler:
             host_dispatches=dispatches, compile_s=compile_s,
             idle_rounds=idle, injit_admit=self.injit_admit,
             items_by_shard=[int(x) for x in np.ravel(items_recv)],
+            distance_lanes=int(lanes.sum()),
             shed=len(shed_qids),
             truncated=sum(1 for r in results if r.truncated),
             quarantined=int(quarantined.sum()),

@@ -3,6 +3,7 @@
 Run as: XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         PYTHONPATH=src python tests/multishard_check.py
 """
+import dataclasses
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
@@ -32,13 +33,17 @@ def main():
     qsh = queries.reshape(S, nq // S, d)
 
     mesh = make_engine_mesh()
-    # (spec_width, kernel_mode): the ref leg drives distance + merge
-    # through the kernel backend's paged/bitonic path under shard_map
-    for spec, kernel_mode in ((0, "jnp"), (4, "jnp"), (4, "ref")):
+    # (spec_width, kernel_mode, capacity_b): the ref legs drive distance
+    # + merge through the kernel backend's paged/bitonic path under
+    # shard_map; capacity_b=8 makes the drop rule fire on both drivers
+    for spec, kernel_mode, cap_b in ((0, "jnp", None), (4, "jnp", None),
+                                     (4, "ref", None), (0, "ref", 8)):
         sp = SearchParams(L=16, W=2, k=10)
         params = EngineParams.lossless(sp, qsh.shape[1], geom.max_degree,
                                        spec_width=spec,
                                        kernel_mode=kernel_mode)
+        if cap_b is not None:
+            params = dataclasses.replace(params, capacity_b=cap_b)
         si, sd, ss = search_sim(consts, qsh, *entry, params, geom)
         di, dd, dst = search_distributed(consts, qsh, *entry, params, geom,
                                          mesh)
@@ -46,14 +51,19 @@ def main():
         np.testing.assert_array_equal(np.asarray(sd), np.asarray(dd))
         np.testing.assert_array_equal(np.asarray(ss["rounds"]),
                                       np.asarray(dst["rounds"]))
-        np.testing.assert_array_equal(np.asarray(ss["pages_unique"]),
-                                      np.asarray(dst["pages_unique"]))
+        for name in ("pages_unique", "items_recv", "drops_b",
+                     "props_sent"):
+            np.testing.assert_array_equal(np.asarray(ss[name]),
+                                          np.asarray(dst[name]),
+                                          err_msg=name)
+        assert (int(np.asarray(ss["drops_b"]).sum()) > 0) == (cap_b == 8)
         # satellite: both drivers report total_rounds per shard, same shape
         assert (np.asarray(ss["total_rounds"]).shape
                 == np.asarray(dst["total_rounds"]).shape == (S,))
         np.testing.assert_array_equal(np.asarray(ss["total_rounds"]),
                                       np.asarray(dst["total_rounds"]))
-        print(f"spec={spec} kernel_mode={kernel_mode}: shard_map == sim OK "
+        print(f"spec={spec} kernel_mode={kernel_mode} capacity_b="
+              f"{params.capacity_b}: shard_map == sim OK "
               f"(rounds={int(np.asarray(ss['rounds']).sum())})")
 
     # streaming scheduler over the shard_map stepper: the distributed

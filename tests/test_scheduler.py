@@ -227,7 +227,8 @@ def test_admit_resets_slot_state(ds):
                                  params=params, geom=geom)
     fresh = engine_init(consts, qB, *entry, params=params, geom=geom)
     for leaf_r, leaf_f, name in zip(readmit, fresh, state._fields):
-        if name in ("items_recv", "pages_unique", "drops_b", "props_sent"):
+        if name in ("items_recv", "distance_lanes", "pages_unique", "drops_b",
+                    "props_sent"):
             continue   # shard-cumulative counters survive by design
         np.testing.assert_array_equal(np.asarray(leaf_r),
                                       np.asarray(leaf_f), err_msg=name)
@@ -959,3 +960,35 @@ def test_session_compiles_stepper_exactly_once():
     assert st.host_dispatches > 1
     assert st.total_rounds > 4
     assert len(st.results) == queries.shape[0]
+
+
+@pytest.mark.parametrize("kernel_mode,qb", [("ref", 8), ("jnp", 8)])
+def test_distance_lanes_counts_tiles_per_round(ds, kernel_mode, qb):
+    """StreamStats.distance_lanes (and stream_summary's) is the sim
+    round's static distance lanes — tiles x tile width of the one
+    distance call over all shards' proposals, or one lane per proposal
+    inline — times the rounds stepped; items_recv never exceeds it."""
+    from repro.core.metrics import stream_summary
+    from repro.kernels.distance.ops import coalesce_num_tiles
+
+    db, queries, packed = ds
+    consts, geom, entry = pack_for_engine(packed)
+    sp = SearchParams(L=16, W=1, k=10)
+    slots = 4
+    params = EngineParams.lossless(sp, slots, geom.max_degree,
+                                   kernel_mode=kernel_mode, coalesce_qb=qb)
+    arrivals = np.random.default_rng(3).integers(0, 10, queries.shape[0])
+    _, _, st = stream_search(consts, geom, params, entry, queries,
+                             num_slots=slots, arrivals=arrivals,
+                             round_chunk=4)
+    S, NP = geom.num_shards, geom.pages_per_shard
+    items = S * slots * sp.W * geom.max_degree
+    if kernel_mode == "jnp":
+        per_round = items
+    else:
+        assert items >= 2 * S * NP          # the coalesced tiles engage
+        per_round = coalesce_num_tiles(items, S * NP, qb) * qb
+    assert st.total_rounds > 0
+    assert st.distance_lanes == per_round * st.total_rounds
+    assert 0 < st.items_recv <= st.distance_lanes
+    assert stream_summary(st)["distance_lanes"] == st.distance_lanes
