@@ -109,14 +109,21 @@ class Build:
         return db, adj, entry, time.perf_counter() - self.t0, self.cached
 
 
+def geometry(cfg: dict):
+    """The program's store geometry for the configuration's index."""
+    from repro.core.luncsr import Geometry
+
+    return Geometry(num_shards=int(cfg["shards"]),
+                    page_size=int(cfg["page_size"]), pages_per_block=4,
+                    dim=int(cfg["dim"]), stripe="striped")
+
+
 def pack(cfg: dict, db: np.ndarray, adj: np.ndarray, entry: int):
     """The program's paged, sharded layout of the index."""
-    from repro.core.luncsr import LUNCSR, Geometry, pack_index
+    from repro.core.luncsr import LUNCSR, pack_index
 
-    geom = Geometry(num_shards=int(cfg["shards"]),
-                    page_size=int(cfg["page_size"]), pages_per_block=4,
-                    dim=db.shape[1], stripe="striped")
-    idx = LUNCSR.from_adjacency(db, adj, geom, entry=entry, pref_width=0)
+    idx = LUNCSR.from_adjacency(db, adj, geometry(cfg), entry=entry,
+                                pref_width=0)
     return pack_index(idx, max_degree=int(cfg["degree"]))
 
 

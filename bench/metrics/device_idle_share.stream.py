@@ -3,7 +3,7 @@
 
 LAYER = "device"
 SOURCE = "device_trace"
-MOVES = "latency_p99_s"
+MOVES = "latency_p95_s"
 
 
 def read(ctx):

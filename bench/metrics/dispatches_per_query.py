@@ -3,7 +3,7 @@
 
 LAYER = "scheduler"
 SOURCE = "program_counter"
-MOVES = "latency_p99_s"
+MOVES = "latency_p95_s"
 
 
 def read(ctx):

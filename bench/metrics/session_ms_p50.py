@@ -6,7 +6,7 @@ import numpy as np
 
 LAYER = "served entry"
 SOURCE = "host_clock"
-MOVES = "latency_p99_s"
+MOVES = "latency_p95_s"
 
 
 def read(ctx):

@@ -9,6 +9,16 @@ by ``frontend.py``). Per-layer metrics are readers of their own,
 ``bench/metrics/<metric>.py``, found by the metric's name. Adding a
 configuration, a mix or a metric takes new files and no edit here.
 
+A configuration may state its index layout under ``"layout"``. Without
+it the index is wholly resident on the cell's chip. With
+``{"device_pages": <frames a shard holds on the device>}`` the index is
+tiered: a shard's vector pages live in host RAM and that many device
+frames cache them, through one ``repro.core.pagestore.PageStore`` built
+in set-up and kept warm across the window's calls. A cell on more than
+one chip serves through the shard_map stepper over a mesh of its chips,
+one shard a chip; a cell on one chip through the one-device (sim)
+stepper. The checks are the same for every layout.
+
 A run: load the seed's index from ``bench/.index_cache``, or build it there
 with the program's graph build in a child process; refuse anything but a
 TPU; make the seed's query pool; place the index on the device; warm up
@@ -82,6 +92,7 @@ def cell_metrics(spec: dict, cell: str, trace: bool) -> list[dict]:
 
 
 def require_chips(count: int, allow_cpu: bool):
+    """The cell's own devices: the first ``count`` JAX sees."""
     import jax
 
     devs = jax.devices()
@@ -90,7 +101,33 @@ def require_chips(count: int, allow_cpu: bool):
                      f"benchmark does not run elsewhere")
     if len(devs) < count:
         raise NoChip(f"the cell needs {count} chips, JAX sees {len(devs)}")
-    return devs
+    return devs[:count]
+
+
+def serving_layout(cfg: dict, chips: int):
+    """The configuration's ``layout``, checked before the index is built:
+    None where the index is wholly resident, else the tiered page store's
+    device frames a shard. A cell on several chips takes one shard a
+    chip."""
+    name, shards = cfg["name"], int(cfg["shards"])
+    if chips > 1 and shards != chips:
+        raise ValueError(
+            f"config {name}: 'shards' is {shards}, but the shard_map "
+            f"stepper over the cell's {chips} chips takes one shard a "
+            f"chip, {chips}")
+    layout = cfg.get("layout")
+    if layout is None:
+        return None
+    if not isinstance(layout, dict) or list(layout) != ["device_pages"]:
+        raise ValueError(f"config {name}: 'layout' must be "
+                         f"{{\"device_pages\": <frames>}}, is {layout!r}")
+    pages = layout["device_pages"]
+    per_shard = index.geometry(cfg).pages_per_shard(int(cfg["n"]))
+    if type(pages) is not int or not 1 <= pages <= per_shard:
+        raise ValueError(
+            f"config {name}: 'layout.device_pages' is {pages!r}, must be "
+            f"1 to {per_shard}, the pages a shard holds")
+    return pages
 
 
 def enable_compile_cache(bench_dir: pathlib.Path) -> str:
@@ -136,6 +173,18 @@ def window_counters(served: frontend.Served, slots: int,
         "pages_unique": sum(s.pages_unique for s in st),
         "retired": len(results),
     }
+
+
+def store_counters(store, before: dict, served: frontend.Served) -> dict:
+    """The tiered page store's counts over the window: its own counters
+    after the window less before it (``StreamStats.prefetch_hits`` is the
+    store's running total, so it is taken here too), the stall rounds of
+    the window's calls, and the share of a shard's pages on the device."""
+    after = store.counters()
+    out = {k: after[k] - before[k] for k in after}
+    out["stalls"] = sum(s.stalls for s in served.stats)
+    out["resident_fraction"] = store.resident_fraction
+    return out
 
 
 class GcPauses:
@@ -273,7 +322,9 @@ def run_cell(spec: dict, cell_name: str, seed: int, seconds: float,
         raise KeyError(f"no cell {cell_name!r} in the benchmark; have "
                        f"{sorted(cells)}")
     cell = cells[cell_name]
+    chips = int(cell["chips"])
     cfg = load_json(bench_dir / "configs" / f"{cell['config']}.json")
+    device_pages = serving_layout(cfg, chips)
     mix = frontend.load(bench_dir / "traffic" / f"{cell['traffic']}.json")
     metrics = cell_metrics(spec, cell_name, trace)
     readers = ({m["name"]: load_reader(bench_dir, m["name"])
@@ -281,7 +332,7 @@ def run_cell(spec: dict, cell_name: str, seed: int, seconds: float,
 
     with index.Build(cfg, seed, bench_dir / ".index_cache",
                      bench_dir.parent) as build:
-        devs = require_chips(int(cell["chips"]), allow_cpu)
+        devs = require_chips(chips, allow_cpu)
         db, adj, entry, build_s, cached = build.result()
     import jax
 
@@ -306,6 +357,22 @@ def run_cell(spec: dict, cell_name: str, seed: int, seconds: float,
         slots, packed.max_degree, kernel_mode=cfg["kernel_mode"],
         coalesce_qb=int(cfg["coalesce_qb"]))
 
+    # the layout's arguments of the served entry; none where the index
+    # is resident on one chip
+    layout_kw, store = {}, None
+    if device_pages is not None:
+        from repro.core.pagestore import PageStore
+
+        store = PageStore(consts, geom, device_pages,
+                          w_select=int(cfg["W"]))
+        params = dataclasses.replace(params, store_pages=store.num_pages)
+        layout_kw["pagestore"] = store
+    if chips > 1:
+        from repro.launch.mesh import make_engine_mesh
+
+        # over the first ``chips`` devices JAX sees: ``devs``
+        layout_kw["mesh"] = make_engine_mesh("lun", num=chips)
+
     pool = int(cfg["query_pool"])
     pool_q = VectorDataset.from_config(cfg).queries(pool, seed)
     pool_slots = S * slots
@@ -315,7 +382,7 @@ def run_cell(spec: dict, cell_name: str, seed: int, seconds: float,
         return scheduler.stream_search(
             consts, geom, params, entry_dev, pool_q[rows],
             num_slots=slots, round_chunk=int(cfg["round_chunk"]),
-            ring_capacity=ring)
+            ring_capacity=ring, **layout_kw)
 
     call(frontend.first_request(mix, pool, pool_slots, seed))
     # what set-up made lives on; the collector need not walk it again
@@ -324,6 +391,7 @@ def run_cell(spec: dict, cell_name: str, seed: int, seconds: float,
     setup_s = time.perf_counter() - T_START
     log(f"setup_s={setup_s:.3f} (ring {ring}, pool {pool_slots} slots)")
 
+    store_before = store.counters() if store is not None else None
     tdir = bench_dir / ".traces" / f"{cell_name}-{os.getpid()}"
     span = jax.profiler.TraceAnnotation
     with CompileGuard() as guard, GcPauses() as pauses:
@@ -339,21 +407,24 @@ def run_cell(spec: dict, cell_name: str, seed: int, seconds: float,
         if trace:
             jax.profiler.stop_trace()
     gc.unfreeze()
-    stats = devs[0].memory_stats() or {}
+    peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devs)
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-              "count": len(devs),
-              "memory_peak_bytes": int(stats.get("peak_bytes_in_use", 0))}
+              "count": len(devs), "memory_peak_bytes": peak}
     log(f"window: {served.answered} queries in {len(served.stats)} calls, "
         f"{served.window_s:.3f} s; compiles in window: {guard.total}")
 
     reduced = None
     if trace:
         events = trace_reduce.load_events(str(tdir))
-        reduced = trace_reduce.reduce(events, frontend.SPANS)
+        reduced = trace_reduce.reduce(events, frontend.SPANS,
+                                      [d.id for d in devs])
         shutil.rmtree(tdir, ignore_errors=True)
         device["busy_s"] = reduced.busy_s
         device["window_s"] = reduced.window_s
     counters = window_counters(served, slots, S)
+    if store is not None:
+        counters.update(store_counters(store, store_before, served))
 
     checks, failed, recall = check_answers(
         cfg, db, adj, entry, pool_q, served, seed, cfg["limits"])
@@ -364,6 +435,7 @@ def run_cell(spec: dict, cell_name: str, seed: int, seconds: float,
     lat = np.concatenate(served.latency_s)
     e2e = {"qps": served.answered / served.window_s,
            "latency_p50_s": float(np.percentile(lat, 50)),
+           "latency_p95_s": float(np.percentile(lat, 95)),
            "latency_p99_s": float(np.percentile(lat, 99)),
            "recall_at_10": recall, "setup_s": setup_s}
     out_metrics = {}

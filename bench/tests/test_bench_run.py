@@ -187,7 +187,8 @@ def test_new_config_mix_and_metric_are_found_by_name(bench_dir,
     # per-layer metrics read the trace; on the CPU there is no device
     # plane, so the reduction is stood in for by a fixed one
     fake = trace_reduce.Reduced(0.5, 1.0, [], [], [], 0.0, 1.0)
-    monkeypatch.setattr(trace_reduce, "reduce", lambda ev, spans: fake)
+    monkeypatch.setattr(trace_reduce, "reduce",
+                        lambda ev, spans, device_ids: fake)
     monkeypatch.setattr(trace_reduce, "load_events", lambda d: [])
     res = run.run_cell(spec, "wide128.pairs", SEED, 1.0, True,
                        bench_dir=bench_dir, allow_cpu=True)

@@ -68,3 +68,14 @@ def test_no_device_plane_is_an_error(events):
     host_only = [e for e in events if e.plane == tr.HOST_PLANE]
     with pytest.raises(ValueError):
         tr.reduce(host_only, [])
+
+
+def test_busy_time_over_the_cells_own_devices(events):
+    # a second chip of the host, one the cell does not use, ran one op
+    other = events + [ev(tr.OPS_LINE, "fusion.2", 100, 100,
+                         plane="/device:TPU:1")]
+    assert tr.reduce(other, [], [0]).busy_s == pytest.approx(350e-9)
+    assert tr.reduce(other, [], [1]).busy_s == pytest.approx(100e-9)
+    assert tr.reduce(other, []).busy_s == pytest.approx(225e-9)
+    with pytest.raises(ValueError, match="cell's"):
+        tr.reduce(other, [], [2])

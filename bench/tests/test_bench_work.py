@@ -63,3 +63,20 @@ def test_every_cell_reports_setup_another_metric_and_a_layer(cell):
     assert run.cell_metrics(SPEC, cell["name"], True)
     assert (BENCH / "configs" / f"{cell['config']}.json").exists()
     assert (BENCH / "traffic" / f"{cell['traffic']}.json").exists()
+
+
+def test_latency_p99_reader_takes_every_query_of_the_window():
+    import types
+
+    import numpy as np
+    import run
+
+    mod = run.load_reader(BENCH, "latency_p99_ms")
+    # 200 queries in two calls: 198 at 0.1 s, the two slowest at 1 and 2 s
+    lat = [np.full(100, 0.1), np.r_[np.full(98, 0.1), 1.0, 2.0]]
+    ctx = types.SimpleNamespace(served=types.SimpleNamespace(latency_s=lat))
+    assert mod.read(ctx) == pytest.approx(1e3 * np.percentile(
+        np.concatenate(lat), 99))
+    assert mod.read(ctx) > 100.0
+    ctx.served.latency_s = []
+    assert mod.read(ctx) is None

@@ -159,11 +159,18 @@ class Reduced(NamedTuple):
                             self.hi) * 1e-9
 
 
-def reduce(events: list[Event], span_names: Iterable[str]) -> Reduced:
+def reduce(events: list[Event], span_names: Iterable[str],
+           device_ids: Iterable[int] | None = None) -> Reduced:
+    """The window's device time, over the planes of ``device_ids`` (the
+    cell's own chips; every device plane where None): a chip of the host
+    that the cell does not use is no idle time of the cell's."""
     lo, hi = window_of(events)
     planes = device_planes(events)
+    if device_ids is not None:
+        ids = {f"/device:TPU:{i}" for i in device_ids}
+        planes = [p for p in planes if p in ids]
     if not planes:
-        raise ValueError("no device plane in the trace")
+        raise ValueError("no device plane of the cell's in the trace")
     busy = sum(busy_ns(events, p, lo, hi) for p in planes) / len(planes)
     return Reduced(busy * 1e-9, (hi - lo) * 1e-9, top_ops(events, lo, hi),
                    idle_gaps(events, planes[0], lo, hi, span_names),
