@@ -264,34 +264,6 @@ class KernelBackend:
             ppage, slot, mask, qvec, qq, db, vnorm,
             qb=qb, mode=self.resolved)
 
-    def translated_item_distances(self, ttab, ppage, slot, mask, qvec,
-                                  qq, frames, vnorm):
-        """:meth:`item_distances` through a tiered-store residency
-        translation table (core/pagestore.py).
-
-        ttab           : (NP,) i32, logical page -> device frame index,
-                         -1 where the page is not resident
-        frames, vnorm  : (P_dev, P, d), (P_dev, P) the device frame
-                         buffer (the hot tier)
-        returns        : (dist (I,), resident (I,) bool). Resident
-                         assignments are computed against their frame
-                         exactly as ``item_distances`` would against a
-                         full store; non-resident ones read nothing
-                         (masked to BIG_DIST) and are reported so the
-                         owner query can stall for the round.
-
-        With an identity table over a full store (``ttab[i] == i``,
-        ``P_dev == NP``) every argument to ``item_distances`` is
-        bit-identical to the untranslated call — resident-fraction 1.0
-        is provably the device-resident path.
-        """
-        frame = ttab[jnp.clip(ppage, 0, ttab.shape[0] - 1)]
-        resident = frame >= 0
-        fpage = jnp.clip(frame, 0, frames.shape[0] - 1)
-        dist = self.item_distances(fpage, slot, mask & resident, qvec,
-                                   qq, frames, vnorm)
-        return dist, resident
-
 
 def paged_view(db: jax.Array, vnorm: jax.Array, page_size: int):
     """Reshape a flat (N, d) store into the paged (NP, P, d) layout the

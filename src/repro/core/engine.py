@@ -36,14 +36,13 @@ shared by two drivers:
                               collectives need fixed per-destination
                               buffers, so ``_fc_bucket`` /
                               ``_fd_distance`` / ``_fe_gather`` post
-                              ``capacity_b``-slot buckets (as the sim
-                              driver still does for the tiered store
-                              and the ``gather_vectors`` baseline).
+                              ``capacity_b``-slot buckets (as
+                              ``search_sim`` still does for the
+                              ``gather_vectors`` baseline).
 
 ``capacity_b`` bounds what one source may send one owner in a round
 (proposals ranked beyond it are dropped and counted in ``drops_b``) on
-both drivers; on the sim driver over a resident store it sizes no
-buffer.
+every path; in the flat round of ``search_sim`` it sizes no buffer.
 
 Equality sim == distributed == single-shard traversal (lossless capacity,
 spec off) is tested in tests/test_engine*.py.
@@ -139,8 +138,8 @@ class EngineParams:
     capacity_b: int                 # proposals one source may send one
                                     # owner a round (more are dropped,
                                     # drops_b); sizes the shard_map
-                                    # driver's buckets, no buffer on the
-                                    # sim driver over a resident store
+                                    # stepper's buckets, no buffer in the
+                                    # one-device flat round
     sort_by_page: bool = True       # dynamic allocating (page-locality stats)
     spec_width: int = 0             # 2nd-order speculative prefetch width
     gather_vectors: bool = False    # baseline: move vectors, not distances
@@ -175,9 +174,10 @@ class EngineParams:
                                     # residency translation table
                                     # (consts["ttab"]) into a fixed-
                                     # capacity device frame buffer — a
-                                    # non-resident page stalls its owner
-                                    # queries for the round instead of
-                                    # reading garbage. 0 = device-resident
+                                    # lane on a non-resident page stalls
+                                    # its row for the round instead of
+                                    # reading garbage (one device only).
+                                    # 0 = device-resident
                                     # store, zero extra ops (bit-identical
                                     # to every pre-tiered path).
     delta_cap: int = 0              # live index (core/live.py): rows of
@@ -242,13 +242,32 @@ class EngineState(NamedTuple):
                              # demanded but not resident (the demand-
                              # fetch set the scheduler serves at the
                              # next chunk boundary)
+    # Tiered store only (None on a resident store, so its traces carry
+    # no such leaf): the row's pending round, one entry per proposal
+    # lane, kept across the rounds it stalls on missed pages
+    lane_i: jax.Array | None = None  # (Qs, M) i32 — the lane's vertex id
+    lane_d: jax.Array | None = None  # (Qs, M) f32 — its distance
+    lane_h: jax.Array | None = None  # (Qs, M) bool — distance held
+    page_wait: jax.Array | None = None
+                             # (store_pages,) i32 — the largest wait key
+                             # (:func:`_wait_keys`) of a row that missed
+                             # the page since the last chunk boundary:
+                             # the demand fetch installs pages in this
+                             # order, oldest waiting row first
 
 
 # ---------------------------------------------------------------------------
 # Stage functions — all operate on one shard's local arrays.
 # ---------------------------------------------------------------------------
+def _row_lanes(params: EngineParams, geom: EngineGeom) -> int:
+    """Proposal lanes of one row's round (``_fc_propose``'s M)."""
+    return params.search.W * (geom.max_degree + params.spec_width)
+
+
 def _init_state(queries, qq, entry_vec, entry_norm, entry_id,
-                params: EngineParams) -> EngineState:
+                params: EngineParams, lanes: int = 0) -> EngineState:
+    """A fresh row per query; ``lanes`` (:func:`_row_lanes`) sizes the
+    tiered store's per-row lane buffers."""
     sp = params.search
     Qs = queries.shape[0]
     L = sp.L
@@ -272,9 +291,16 @@ def _init_state(queries, qq, entry_vec, entry_norm, entry_id,
     zs = jnp.int32(0)
     dl = params.deadline_rounds if params.deadline_rounds > 0 else NEVER
     pz = jnp.zeros((params.store_pages,), bool)
+    tier = ()
+    if params.store_pages:
+        tier = (jnp.full((Qs, lanes), INVALID, jnp.int32),
+                jnp.full((Qs, lanes), BIG_DIST, jnp.float32),
+                jnp.zeros((Qs, lanes), bool),
+                jnp.zeros((params.store_pages,), jnp.int32))
     return EngineState(cand_d, cand_i, cand_e, bloom, z.astype(bool),
                        z, z, z, jnp.full((Qs,), dl, jnp.int32),
-                       z.astype(bool), zs, zs, zs, zs, zs, zs, pz, pz)
+                       z.astype(bool), zs, zs, zs, zs, zs, zs, pz, pz,
+                       *tier)
 
 
 def _fa_select(state: EngineState, params: EngineParams, geom: EngineGeom):
@@ -400,7 +426,7 @@ def _fc_bucket(keep_c, queries, qq, params: EngineParams,
 
 
 def _fd_distance(recv, db, vnorm, blk_perm, my_shard,
-                 params: EngineParams, geom: EngineGeom, ttab=None):
+                 params: EngineParams, geom: EngineGeom):
     """Owner SiN: translate id -> physical page/slot, compute distances.
 
     In gather_vectors mode returns the raw vectors instead (baseline).
@@ -413,16 +439,6 @@ def _fd_distance(recv, db, vnorm, blk_perm, my_shard,
     huge-negative distance exactly as damaged media would, on every
     visit to that page. Corruption models the SiN distance read path,
     so the gather_vectors baseline is exempt.
-
-    With the tiered page store (``params.store_pages > 0``) ``db`` /
-    ``vnorm`` are the fixed-capacity device *frame* buffers and
-    ``ttab`` the (store_pages,) residency translation table: the read
-    goes through :meth:`KernelBackend.translated_item_distances`, a
-    ``"miss"`` lane rides the reply so the requester can stall queries
-    that demanded a cold page, and the stage additionally returns the
-    shard's per-chunk page touch/miss bitmaps (the prefetcher's demand
-    + hit-accounting signal). An identity table over a full store is
-    bit-identical to the untranslated read.
     """
     vid = recv["vid"]                              # (S, C_B)
     mask = recv["mask"]
@@ -430,7 +446,7 @@ def _fd_distance(recv, db, vnorm, blk_perm, my_shard,
     flat_vid = jnp.clip(vid.reshape(-1), 0, geom.n - 1)
     flat_mask = mask.reshape(-1)
     ppage = geom.phys_page(flat_vid, blk_perm)
-    npages = params.store_pages if params.store_pages else db.shape[0]
+    npages = db.shape[0]
     ppage = jnp.clip(ppage, 0, npages - 1)
     slot = flat_vid % geom.page_size
 
@@ -439,29 +455,6 @@ def _fd_distance(recv, db, vnorm, blk_perm, my_shard,
     first = jnp.concatenate(
         [jnp.ones((1,), bool), sorted_pages[1:] != sorted_pages[:-1]])
     uniq = (first & (sorted_pages != 2**30)).sum().astype(jnp.int32)
-
-    if params.store_pages:
-        if params.gather_vectors:
-            raise NotImplementedError(
-                "the gather_vectors baseline moves raw vectors, not "
-                "page reads — it has no tiered page store")
-        dist, resident = params.backend.translated_item_distances(
-            ttab, ppage, slot, flat_mask, recv["qvec"].reshape(S * C, -1),
-            recv["qq"].reshape(-1), db, vnorm)
-        if params.faults is not None and params.faults.any_corrupt:
-            bad = ftinject.bad_page_mask(params.faults, ppage, my_shard)
-            dist = jnp.where(bad & flat_mask,
-                             ftinject.corrupt_value(params.faults), dist)
-        missed = flat_mask & ~resident
-        send = {"dist": dist.reshape(S, C), "miss": missed.reshape(S, C)}
-        # per-chunk page bitmaps: scatter True at the touched/missed
-        # logical pages (masked lanes write OOB and drop)
-        touch = jnp.zeros((npages,), bool).at[
-            jnp.where(flat_mask & resident, ppage, npages)].set(
-            True, mode="drop")
-        pmiss = jnp.zeros((npages,), bool).at[
-            jnp.where(missed, ppage, npages)].set(True, mode="drop")
-        return send, items, uniq, touch, pmiss
 
     if params.gather_vectors:
         v = db[ppage, slot].astype(jnp.float32)    # (S*C, d)
@@ -481,22 +474,37 @@ def _fd_distance(recv, db, vnorm, blk_perm, my_shard,
 
 
 def _fd_distance_flat(keep_c, queries, qq, db, vnorm, blk_perm,
-                      params: EngineParams, geom: EngineGeom):
-    """Phase D of every shard at once (sim driver, resident store): one
-    :meth:`KernelBackend.item_distances` call over the flat
-    (S*Qs*M,) list of all shards' proposals, against the (S*NP, P, d)
-    view of the shard-major store, with the owner folded into the page
-    key (``owner * NP + page``). Query tiles are packed from
+                      params: EngineParams, geom: EngineGeom, store=None):
+    """Phase D of every shard at once (one-device path): one
+    :meth:`KernelBackend.item_distances` call over the flat (S*Qs*M,)
+    list of all shards' proposals, against the (S*NP, P, d) view of the
+    shard-major store, with the owner folded into the page key
+    (``owner * NP + page``). Query tiles are packed from
     ``queries[s, q]`` directly; nothing is bucketed.
 
     ``keep_c`` leaves, ``queries`` (S, Qs, d) and ``qq`` (S, Qs) carry
     the shard axis. Returns (dist (S, Qs*M) in proposal order, BIG_DIST
-    where not sent; per-owner items (S,) and unique pages (S,), equal
+    where not read; per-owner items (S,) and unique pages (S,), equal
     to the bucketed :func:`_fd_distance`'s counts; distance lanes (S,),
     the stage's static count booked on shard 0).
+
+    Tiered store: ``store`` is ``(ttab, held, key)`` and ``db`` /
+    ``vnorm`` are the (S, D, P[, d]) device frames. ``ttab`` (S, NP)
+    translates each lane's (owner, page) to frame ``owner * D + frame``
+    of the (S*D, P, d) view, -1 where the page is not resident;
+    ``held`` (S, Qs, M) marks lanes whose distance the row already
+    holds from an earlier try of the round (not read again); ``key``
+    (S, Qs) is each row's wait key. A fifth value is returned, the
+    per-shard dict the merge takes: ``miss`` (S, Qs*M) lanes wanted
+    but not resident, ``held``, and the (S, NP) page bitmaps ``touch``
+    (read from a frame) and ``pmiss``, and ``wait``, the largest key of
+    a row that missed each page. Every page counted is a logical one;
+    an identity table over a full store reads exactly what the
+    resident path reads.
     """
     S, Qs, d = queries.shape
-    NP, P = vnorm.shape[1:]
+    NP, P = vnorm.shape[1:] if store is None else (store[0].shape[1],
+                                                   vnorm.shape[2])
     M = keep_c["props"].shape[-1]
     ok = keep_c["ok"].reshape(-1)
     vid = jnp.clip(keep_c["props"].reshape(-1), 0, geom.n - 1)
@@ -507,28 +515,50 @@ def _fd_distance_flat(keep_c, queries, qq, db, vnorm, blk_perm,
     qvec = queries.reshape(S * Qs, d)[qrow]
     if params.payload_bf16:
         qvec = qvec.astype(jnp.bfloat16)
+    if store is None:
+        read, key, nkeys = ok, gpage, S * NP
+    else:
+        ttab, held, wait_key = store
+        D = vnorm.shape[1]
+        want = ok & ~held.reshape(-1)
+        frame = ttab.reshape(-1)[gpage]
+        read = want & (frame >= 0)
+        key, nkeys = owner * D + jnp.clip(frame, 0, D - 1), S * D
     dist = params.backend.item_distances(
-        gpage, vid % geom.page_size, ok, qvec, qq.reshape(-1)[qrow],
-        db.reshape(S * NP, P, d), vnorm.reshape(S * NP, P))
+        key, vid % geom.page_size, read, qvec, qq.reshape(-1)[qrow],
+        db.reshape(nkeys, P, d), vnorm.reshape(nkeys, P))
     if params.faults is not None and params.faults.any_corrupt:
         bad = ftinject.bad_page_mask(params.faults, ppage, owner)
-        dist = jnp.where(bad & ok, ftinject.corrupt_value(params.faults),
+        dist = jnp.where(bad & read, ftinject.corrupt_value(params.faults),
                          dist)
     items = ((owner[:, None] == jnp.arange(S, dtype=jnp.int32))
-             & ok[:, None]).sum(axis=0).astype(jnp.int32)
+             & read[:, None]).sum(axis=0).astype(jnp.int32)
     touched = jnp.zeros((S * NP,), bool).at[
-        jnp.where(ok, gpage, S * NP)].set(True, mode="drop")
+        jnp.where(read, gpage, S * NP)].set(True, mode="drop")
     uniq = touched.reshape(S, NP).sum(axis=1).astype(jnp.int32)
     lanes = jnp.zeros((S,), jnp.int32).at[0].set(
-        params.backend.distance_lanes(ok.shape[0], S * NP))
-    return dist.reshape(S, Qs * M), items, uniq, lanes
+        params.backend.distance_lanes(ok.shape[0], nkeys))
+    dist = dist.reshape(S, Qs * M)
+    if store is None:
+        return dist, items, uniq, lanes
+    missed = want & (frame < 0)
+    at_miss = jnp.where(missed, gpage, S * NP)
+    tier = {
+        "miss": missed.reshape(S, Qs * M), "held": held,
+        "touch": touched.reshape(S, NP),
+        "pmiss": jnp.zeros((S * NP,), bool).at[at_miss].set(
+            True, mode="drop").reshape(S, NP),
+        "wait": jnp.zeros((S * NP,), jnp.int32).at[at_miss].max(
+            wait_key.reshape(-1)[qrow], mode="drop").reshape(S, NP),
+    }
+    return dist, items, uniq, lanes, tier
 
 
 def _fe_gather(recv_d, keep_c, queries, qq, params: EngineParams):
-    """Requester, bucketed exchange: each proposal's reply out of its
-    owner's bucket, in proposal order — (dist (Qs*M,), miss (Qs*M,)
-    bool or None). In the gather_vectors baseline the requester
-    computes the distances from the returned vectors."""
+    """Requester, bucketed exchange: each proposal's distance out of its
+    owner's reply bucket, in proposal order (Qs*M,). In the
+    gather_vectors baseline the requester computes the distances from
+    the returned vectors."""
     dest, rank, ok = keep_c["dest"], keep_c["rank"], keep_c["ok"]
     C = params.capacity_b
     if params.gather_vectors:
@@ -537,12 +567,8 @@ def _fe_gather(recv_d, keep_c, queries, qq, params: EngineParams):
         Qs, M = keep_c["props"].shape
         qidx = jnp.repeat(jnp.arange(Qs, dtype=jnp.int32), M)
         qv = jnp.sum(queries[qidx].astype(jnp.float32) * vec, axis=-1)
-        dist = qq[qidx] - 2.0 * qv + vn
-    else:
-        dist = gather_from_buckets(recv_d["dist"], dest, rank, ok, C)
-    miss = (gather_from_buckets(recv_d["miss"], dest, rank, ok, C)
-            if params.store_pages else None)
-    return dist, miss
+        return qq[qidx] - 2.0 * qv + vn
+    return gather_from_buckets(recv_d["dist"], dest, rank, ok, C)
 
 
 def _bucketed_lanes(params: EngineParams, geom: EngineGeom, npages: int,
@@ -557,8 +583,8 @@ def _bucketed_lanes(params: EngineParams, geom: EngineGeom, npages: int,
 
 
 def _fe_merge(state: EngineState, keep_a, keep_c, dist, items, uniq,
-              lanes, miss=None, page_touch=None, page_miss=None,
-              params: EngineParams = None, geom: EngineGeom = None):
+              lanes, tier=None, params: EngineParams = None,
+              geom: EngineGeom = None):
     """Requester: bloom-insert, merge, re-terminate.
 
     ``dist`` holds each proposal's distance in proposal order (Qs*M,),
@@ -566,30 +592,33 @@ def _fe_merge(state: EngineState, keep_a, keep_c, dist, items, uniq,
     reply buckets (:func:`_fe_gather`); ``items`` / ``uniq`` / ``lanes``
     are this shard's phase-D counts.
 
-    Tiered store (``params.store_pages > 0``): ``miss`` marks
-    assignments whose page was not device-resident. A query
-    with any missed assignment **stalls** — its entire round is masked
-    exactly like a ``done`` row's (candidates, bloom, rounds, n_dist
-    all restored), so next round it re-selects the same frontier and
-    re-proposes the same set, by which time the scheduler has demand-
-    fetched the page at the chunk boundary. Stalled rounds show up as
-    ``age - rounds`` (the serving clock advances, the work clock does
-    not). ``page_touch`` / ``page_miss`` are this shard's stage-D
-    bitmaps, OR-accumulated into the state for the boundary fetcher.
+    Tiered store: ``tier`` is this shard's part of the flat stage's
+    tiered output (:func:`_fd_distance_flat`). A lane's distance is the
+    one just read, or the one the row holds from an earlier try of the
+    same round (``held``). A row with an accepted lane that has neither
+    (its page missed) **stalls**: its round is masked exactly like a
+    ``done`` row's (candidates, bloom, rounds, n_dist all restored),
+    and the distances it did get are kept in ``lane_*``, so it re-
+    selects the same frontier next round, re-proposes the same lanes
+    and reads only the ones still missing. It merges once every lane
+    has its distance, so the merge sees the distances an all-resident
+    round sees, and needs only one of its pages resident at a time.
+    Stalled rounds show up as ``age - rounds`` (the serving clock
+    advances, the work clock does not). The page bitmaps and wait keys
+    accumulate into the state for the boundary fetcher.
     """
     Qs, L = state.cand_d.shape
     props = keep_c["props"]                        # (Qs, M)
     M = props.shape[1]
     accepted = keep_c["ok"].reshape(Qs, M)
     dist = jnp.where(accepted, dist.reshape(Qs, M), BIG_DIST)
-    if params.store_pages:
-        # any missed page stalls the whole query for the round: mask it
-        # like a done row (state restored below) so it retries the
-        # identical round after the boundary fetch. A live row always
-        # has an unexpanded candidate (else it would be done), so a
-        # stalled row can never be re-terminated by the done update.
-        stall = ((miss.reshape(Qs, M) & accepted).any(axis=1)
-                 & ~state.done)
+    if tier is not None:
+        held = tier["held"] & accepted
+        got = held | (accepted & ~tier["miss"].reshape(Qs, M))
+        dist = jnp.where(held, state.lane_d, dist)
+        # a live row always has an unexpanded candidate (else it would
+        # be done), so a stalled row is never re-terminated below
+        stall = (accepted & ~got).any(axis=1) & ~state.done
         keep = state.done | stall
         acc_eff = accepted & ~stall[:, None]
     else:
@@ -615,9 +644,19 @@ def _fe_merge(state: EngineState, keep_a, keep_c, dist, items, uniq,
     n_dist = state.n_dist + jnp.where(worked, acc_eff.sum(-1), 0
                                       ).astype(jnp.int32)
     done = state.done | ~((~cand_e) & (cand_i != ID_SENTINEL)).any(axis=1)
-    if params.store_pages:
-        p_touch = state.page_touch | page_touch
-        p_miss = state.page_miss | page_miss
+    tier_leaves = ()
+    if tier is not None:
+        # a stalled row keeps its round's lanes; a merged row's are
+        # spent; a parked row (done, or its shard fault-stalled) keeps
+        # what it held
+        st = stall[:, None]
+        tier_leaves = (
+            jnp.where(st, props, state.lane_i),
+            jnp.where(st, dist, state.lane_d),
+            jnp.where(st, got, state.lane_h & state.done[:, None]),
+            jnp.maximum(state.page_wait, tier["wait"]))
+        p_touch = state.page_touch | tier["touch"]
+        p_miss = state.page_miss | tier["pmiss"]
     else:
         p_touch, p_miss = state.page_touch, state.page_miss
     return EngineState(
@@ -626,7 +665,7 @@ def _fe_merge(state: EngineState, keep_a, keep_c, dist, items, uniq,
         state.items_recv + items, state.distance_lanes + lanes,
         state.pages_unique + uniq, state.drops_b + keep_c["drops"],
         state.props_sent + acc_eff.sum().astype(jnp.int32),
-        state.quarantined + quar, p_touch, p_miss)
+        state.quarantined + quar, p_touch, p_miss, *tier_leaves)
 
 
 # ---------------------------------------------------------------------------
@@ -657,7 +696,7 @@ def _round(state, consts, params: EngineParams, geom: EngineGeom, a2a,
     send_d, items, uniq = _fd_distance(recv_c, consts["db"], consts["vnorm"],
                                        consts["blk_perm"], my_shard, params,
                                        geom)
-    dist, _ = _fe_gather(a2a(send_d), keep_c, queries, qq, params)
+    dist = _fe_gather(a2a(send_d), keep_c, queries, qq, params)
     lanes = _bucketed_lanes(params, geom, consts["db"].shape[0],
                             *keep_c["props"].shape)
     return _fe_merge(state, keep_a, keep_c, dist, items, uniq,
@@ -761,6 +800,16 @@ def pack_for_engine(packed: PackedIndex):
                           jnp.int32(packed.entry))
 
 
+def _wait_keys(age):
+    """(S, Qs) i32 demand-fetch priority of each row: the oldest row
+    first, ties broken by the lower global row index, so exactly one row
+    leads every shard's fetch order. Unique while a row's age stays below
+    2**31 / (S*Qs) rounds."""
+    S, Qs = age.shape
+    rows = jnp.arange(S * Qs, dtype=jnp.int32).reshape(S, Qs)
+    return age * (S * Qs) + (S * Qs - 1 - rows)
+
+
 def _sim_round(state, consts, queries, qq, spec_w, params: EngineParams,
                geom: EngineGeom):
     """One engine round in sim comm: vmapped stages, all_to_all == swapaxes.
@@ -774,9 +823,10 @@ def _sim_round(state, consts, queries, qq, spec_w, params: EngineParams,
     round's own proposals (:func:`_fd_distance_flat`): one distance call
     over the (S*Qs*M,) list, with no (S, S, capacity_b) buckets to
     scatter, tile or gather, and ``_fe_merge`` reads each proposal's
-    distance off its result. The tiered store (``store_pages > 0``) and the
-    ``gather_vectors`` baseline keep the bucketed stages, whose reply
-    lanes (``miss``, raw vectors) the flat stage does not carry."""
+    distance off its result. The tiered store (``store_pages > 0``)
+    reads the same way through its translation table. Only the
+    ``gather_vectors`` baseline keeps the bucketed stages, whose reply
+    carries raw vectors."""
 
     def a2a(tree):
         return jax.tree_util.tree_map(lambda x: jnp.swapaxes(x, 0, 1), tree)
@@ -794,7 +844,18 @@ def _sim_round(state, consts, queries, qq, spec_w, params: EngineParams,
     send_b = vfb(recv_a, consts["adj"], consts["pref"])
     recv_b = a2a(send_b)
     keep_c = vfc(state, keep_a, recv_b, spec_w, shard_ids)
-    if not (params.store_pages or params.gather_vectors):
+    if params.store_pages:
+        if params.gather_vectors:
+            raise NotImplementedError(
+                "the gather_vectors baseline moves raw vectors, not "
+                "page reads — it has no tiered page store")
+        held = state.lane_h & (state.lane_i == keep_c["props"])
+        out = _fd_distance_flat(
+            keep_c, queries, qq, consts["db"], consts["vnorm"],
+            consts["blk_perm"], params, geom,
+            store=(consts["ttab"], held, _wait_keys(state.age)))
+        return vfe(state, keep_a, keep_c, *out)
+    if not params.gather_vectors:
         dist, items, uniq, lanes = _fd_distance_flat(
             keep_c, queries, qq, consts["db"], consts["vnorm"],
             consts["blk_perm"], params, geom)
@@ -803,29 +864,16 @@ def _sim_round(state, consts, queries, qq, spec_w, params: EngineParams,
     vbucket = jax.vmap(functools.partial(_fc_bucket, params=params,
                                          geom=geom))
     recv_c = a2a(vbucket(keep_c, queries, qq))
-    touch = pmiss = None
-    if params.store_pages:
-        # tiered store: stage D reads frames through the translation
-        # table and returns per-shard touch/miss bitmaps, which the
-        # merge accumulates into the state (and stalls missed queries)
-        vfd = jax.vmap(
-            lambda recv, db, vn, bp, ms, tt: _fd_distance(
-                recv, db, vn, bp, ms, params, geom, tt))
-        send_d, items, uniq, touch, pmiss = vfd(
-            recv_c, consts["db"], consts["vnorm"], consts["blk_perm"],
-            shard_ids, consts["ttab"])
-    else:
-        vfd = jax.vmap(functools.partial(_fd_distance, params=params,
-                                         geom=geom))
-        send_d, items, uniq = vfd(recv_c, consts["db"], consts["vnorm"],
-                                  consts["blk_perm"], shard_ids)
-    dist, miss = jax.vmap(functools.partial(_fe_gather, params=params))(
+    vfd = jax.vmap(functools.partial(_fd_distance, params=params,
+                                     geom=geom))
+    send_d, items, uniq = vfd(recv_c, consts["db"], consts["vnorm"],
+                              consts["blk_perm"], shard_ids)
+    dist = jax.vmap(functools.partial(_fe_gather, params=params))(
         a2a(send_d), keep_c, queries, qq)
     lanes = jnp.full((S,), _bucketed_lanes(
         params, geom, consts["db"].shape[1], *keep_c["props"].shape[1:]),
         jnp.int32)
-    return vfe(state, keep_a, keep_c, dist, items, uniq, lanes, miss, touch,
-               pmiss)
+    return vfe(state, keep_a, keep_c, dist, items, uniq, lanes)
 
 
 @functools.partial(jax.jit, static_argnames=("params", "geom"))
@@ -836,7 +884,8 @@ def search_sim(consts, queries, entry_vec, entry_norm, entry_id,
 
     state0 = jax.vmap(
         lambda q, qn: _init_state(q, qn, entry_vec, entry_norm, entry_id,
-                                  params))(queries, qq)
+                                  params, _row_lanes(params, geom)))(
+        queries, qq)
     spec_w = jnp.full(queries.shape[:2], params.spec_width, jnp.int32)
 
     def body(carry):
@@ -994,11 +1043,13 @@ def engine_init(consts, queries, entry_vec, entry_norm, entry_id,
     ``entry_vec`` is either the global entry vertex ((d,), every shard
     seeds there) or per-shard entries ((S, d), routed legs seed at their
     home shard's local medoid)."""
-    del consts, geom
+    del consts
     qq = jnp.sum(queries.astype(jnp.float32) ** 2, axis=-1)
     ax = 0 if jnp.ndim(entry_vec) == 2 else None
+    lanes = _row_lanes(params, geom)
     return jax.vmap(
-        lambda q, qn, ev, en, ei: _init_state(q, qn, ev, en, ei, params),
+        lambda q, qn, ev, en, ei: _init_state(q, qn, ev, en, ei, params,
+                                              lanes),
         in_axes=(0, 0, ax, ax, ax))(queries, qq, entry_vec, entry_norm,
                                     entry_id)
 
@@ -1028,7 +1079,9 @@ def _admit_rows(state: EngineState, queries, admit_mask, new_q,
     shard-cumulative counters pass through untouched."""
     q = jnp.where(admit_mask[..., None], new_q, queries)
     qq = jnp.sum(q.astype(jnp.float32) ** 2, axis=-1)
-    fresh = _init_state(q, qq, entry_vec, entry_norm, entry_id, params)
+    lanes = 0 if state.lane_h is None else state.lane_h.shape[-1]
+    fresh = _init_state(q, qq, entry_vec, entry_norm, entry_id, params,
+                        lanes)
 
     def rows(cur, new):
         m = admit_mask.reshape(admit_mask.shape
@@ -1046,7 +1099,10 @@ def _admit_rows(state: EngineState, queries, admit_mask, new_q,
         jnp.where(admit_mask, False, state.truncated),
         state.items_recv, state.distance_lanes, state.pages_unique,
         state.drops_b, state.props_sent, state.quarantined,
-        state.page_touch, state.page_miss)
+        state.page_touch, state.page_miss,
+        *(() if state.lane_h is None else
+          (rows(state.lane_i, fresh.lane_i), rows(state.lane_d, fresh.lane_d),
+           rows(state.lane_h, fresh.lane_h), state.page_wait)))
     return state, q
 
 
@@ -1176,7 +1232,10 @@ def engine_run_chunk(consts, state: EngineState, queries, spec_state,
       * ``stop_on_finish`` (bool) ends the chunk as soon as *any* row
         that was live at entry finishes — the host sets it whenever
         unadmitted queries remain, so a freed slot is refilled on
-        exactly the round the per-round scheduler would have.
+        exactly the round the per-round scheduler would have;
+      * on the tiered store, a round that moved no row (:func:`_moved`)
+        ends the chunk: every live row waits on pages only the next
+        boundary can fetch.
 
     This is the *host-paced-admission* chunk: the exits above collapse
     chunk length toward one round while the pending queue drains.
@@ -1199,25 +1258,39 @@ def engine_run_chunk(consts, state: EngineState, queries, spec_state,
     budget = jnp.minimum(jnp.asarray(budget, jnp.int32), jnp.int32(K))
     stop = jnp.asarray(stop_on_finish, bool)
 
+    tiered = params.store_pages > 0
+
     def round_fn(st, sw):
         return _sim_round(st, consts, queries, qq, sw, params, geom)
 
     def cond(carry):
-        st, _, _, _, _, _, _, _, j, _, _ = carry
+        st, _, _, _, _, _, _, _, j, _, _ = carry[:11]
         fin_any = (st.done & live0).any()
-        return (j < budget) & (~st.done).any() & ~(stop & fin_any)
+        go = (j < budget) & (~st.done).any() & ~(stop & fin_any)
+        return go & carry[11] if tiered else go
 
     def body(carry):
-        return _chunk_round(carry, round_fn, params.search.rounds_cap,
-                            dynamic, spec_cfg)
+        out = _chunk_round(carry[:11], round_fn, params.search.rounds_cap,
+                           dynamic, spec_cfg)
+        return out + (_moved(carry[0], out[0]),) if tiered else out
 
     zeros_k = jnp.zeros((K,), jnp.int32)
     (state, spec_w, hit, peak, phit, ppeak, _, _, steps, live_cnt,
      width_sum) = jax.lax.while_loop(
         cond, body, (state, spec_w, hit, peak, phit, ppeak, state.n_dist,
-                     state.pages_unique, jnp.int32(0), zeros_k, zeros_k))
+                     state.pages_unique, jnp.int32(0), zeros_k, zeros_k)
+        + ((jnp.bool_(True),) if tiered else ()))[:11]
     return (state, (spec_w, hit, peak, phit, ppeak), steps, live_cnt,
             width_sum)
+
+
+def _moved(before: EngineState, after: EngineState):
+    """Whether a round changed any row: merged a round or finished. On
+    the tiered store a round that changed none leaves every live row
+    waiting on pages that only the next chunk boundary can fetch, so
+    the chunk ends there instead of stalling through its budget."""
+    return ((after.rounds != before.rounds).any()
+            | (after.done != before.done).any())
 
 
 def _seat_pending(free, cursor, avail, offset, pend_q, queries_rows):
@@ -1285,9 +1358,10 @@ def engine_run_chunk_admit(consts, state: EngineState, queries, spec_state,
 
     The chunk exits early (traced) only when there is genuinely nothing
     to do: no live row and no pending entry arrived by the current
-    boundary. Idle gaps (pool empty until a future arrival) stay
-    host-side — the scheduler jumps the serving clock without a
-    dispatch.
+    boundary, or, on the tiered store, a round that moved and seated no
+    row (every live row waits on the next boundary's fetch). Idle gaps
+    (pool empty until a future arrival) stay host-side — the scheduler
+    jumps the serving clock without a dispatch.
 
     With a fault plan on ``params`` (ft/inject.py), shard kill/delay
     windows are evaluated against the global round ``t0 + j`` at every
@@ -1354,15 +1428,18 @@ def engine_run_chunk_admit(consts, state: EngineState, queries, spec_state,
     else:
         avail_of = _pending_avail
 
+    tiered = params.store_pages > 0
+
     def cond(carry):
         st, q, sw, hi, pk, phi, ppk, cur, prev_nd, prev_pg, j = carry[:11]
         avail = avail_of(pend_arr, cur, t0 + j)
-        return ((j < budget)
-                & ((~st.done).any() | (avail.sum() > 0)))
+        go = ((j < budget)
+              & ((~st.done).any() | (avail.sum() > 0)))
+        return go & carry[20] if tiered else go
 
     def body(carry):
         (st, q, sw, hi, pk, phi, ppk, cur, prev_nd, prev_pg, j, lc, ws,
-         aq, ri, rd, rr, rn, ra, rt) = carry
+         aq, ri, rd, rr, rn, ra, rt) = carry[:20]
         # -- boundary j (global round t0 + j): record the would-be-
         # evicted rows' results, then seat arrived pending queries
         fin_i, fin_d = capture_fin(st, q)
@@ -1404,6 +1481,7 @@ def engine_run_chunk_admit(consts, state: EngineState, queries, spec_state,
         # (non-admitted rows' n_dist only moves in rounds, so this is
         # the carried value for them either way).
         qq = jnp.sum(q.astype(jnp.float32) ** 2, axis=-1)
+        seated = st
         st, sw, hi, pk, phi, ppk, prev_nd, prev_pg, j, lc, ws = \
             _chunk_round(
                 (st, sw, hi, pk, phi, ppk, st.n_dist, st.pages_unique,
@@ -1412,8 +1490,11 @@ def engine_run_chunk_admit(consts, state: EngineState, queries, spec_state,
                                         geom),
                 params.search.rounds_cap, dynamic, spec_cfg,
                 stall=None if stall_fn is None else stall_fn(t0 + j))
-        return (st, q, sw, hi, pk, phi, ppk, cur, prev_nd, prev_pg, j,
-                lc, ws, aq, ri, rd, rr, rn, ra, rt)
+        out = (st, q, sw, hi, pk, phi, ppk, cur, prev_nd, prev_pg, j,
+               lc, ws, aq, ri, rd, rr, rn, ra, rt)
+        if tiered:
+            out += (_moved(seated, st) | mask.any(),)
+        return out
 
     zeros_k = jnp.zeros((K,), jnp.int32)
     zeros_sq = jnp.zeros((K, S, Qs), jnp.int32)
@@ -1423,10 +1504,12 @@ def engine_run_chunk_admit(consts, state: EngineState, queries, spec_state,
              jnp.full((K, S, Qs, k), INVALID, jnp.int32),
              jnp.zeros((K, S, Qs, k), jnp.float32), zeros_sq, zeros_sq,
              zeros_sq, jnp.zeros((K, S, Qs), bool))
+    if tiered:
+        carry += (jnp.bool_(True),)
     (state, queries, spec_w, hit, peak, phit, ppeak, cursor, _, _, steps,
      live_cnt, width_sum, admit_qidx, ret_i, ret_d, ret_rounds,
      ret_ndist, ret_age, ret_trunc) = jax.lax.while_loop(cond, body,
-                                                         carry)
+                                                         carry)[:20]
     return (state, queries, (spec_w, hit, peak, phit, ppeak), steps,
             live_cnt, width_sum, admit_qidx, ret_i, ret_d, ret_rounds,
             ret_ndist, ret_age, ret_trunc, cursor)
@@ -1486,7 +1569,9 @@ def make_stepper(params: EngineParams, geom: EngineGeom, mesh=None,
         return jax.tree_util.tree_map(
             lambda x: jax.lax.all_to_all(x, axis_name, 0, 0), tree)
 
-    nleaves = len(EngineState._fields)
+    # the mesh refuses the tiered store, so its states end at the
+    # resident leaves (the lane buffers are None)
+    nleaves = EngineState._fields.index("lane_i")
     sp = params.search
 
     # -- admission under shard_map: per-row math with no communication,
@@ -1500,7 +1585,7 @@ def make_stepper(params: EngineParams, geom: EngineGeom, mesh=None,
             evec, enorm, eid = evec[0], enorm[0], eid[0]
         st, ql = _admit_rows(state, q[0], mask[0], new_q[0], evec,
                              enorm, eid, params)
-        return tuple(leaf[None] for leaf in st), ql[None]
+        return tuple(leaf[None] for leaf in st[:nleaves]), ql[None]
 
     entry_specs = ((P(axis_name),) if routed else (P(),)) * 3
     f_admit = jax.jit(_shard_map_fn(
@@ -1510,7 +1595,7 @@ def make_stepper(params: EngineParams, geom: EngineGeom, mesh=None,
 
     def admit(state, queries, admit_mask, new_q, evec, enorm, eid):
         leaves, q = f_admit(queries, admit_mask, new_q, evec, enorm,
-                            eid, *state)
+                            eid, *state[:nleaves])
         return EngineState(*leaves), q
 
     def local_round(db, vnorm, adj, pref, blk_perm, q, spec_w, *leaves):
@@ -1522,7 +1607,7 @@ def make_stepper(params: EngineParams, geom: EngineGeom, mesh=None,
         state = EngineState(*(leaf[0] for leaf in leaves))
         state = _round(state, lc, params, geom, a2a, spec_w[0],
                        jax.lax.axis_index(axis_name))
-        return tuple(leaf[None] for leaf in state)
+        return tuple(leaf[None] for leaf in state[:nleaves])
 
     in_specs = (P(axis_name),) * 7 + (P(axis_name),) * nleaves
     out_specs = (P(axis_name),) * nleaves
@@ -1533,7 +1618,7 @@ def make_stepper(params: EngineParams, geom: EngineGeom, mesh=None,
                                   queries.shape[:2])
         leaves = f(consts["db"], consts["vnorm"], consts["adj"],
                    consts["pref"], consts["blk_perm"], queries,
-                   spec_w, *state)
+                   spec_w, *state[:nleaves])
         return EngineState(*leaves)
 
     # -- chunked round loop under shard_map: the while_loop's exit tests
@@ -1582,8 +1667,8 @@ def make_stepper(params: EngineParams, geom: EngineGeom, mesh=None,
                      jnp.int32(0), zeros_k, zeros_k)
             (st, sw, hi, pk, phi, ppk, _, _, steps, _, _, lcnt,
              wsum) = jax.lax.while_loop(cond, body, carry)
-            return (tuple(leaf[None] for leaf in st), sw[None], hi[None],
-                    pk[None], phi[None], ppk[None], steps[None],
+            return (tuple(leaf[None] for leaf in st[:nleaves]), sw[None],
+                    hi[None], pk[None], phi[None], ppk[None], steps[None],
                     lcnt[None], wsum[None])
 
         return local_chunk
@@ -1607,7 +1692,7 @@ def make_stepper(params: EngineParams, geom: EngineGeom, mesh=None,
             consts["db"], consts["vnorm"], consts["adj"], consts["pref"],
             consts["blk_perm"], queries, sw, hi, pk, phi, ppk, cfg,
             jnp.asarray(budget, jnp.int32), jnp.asarray(stop_on_finish),
-            *state)
+            *state[:nleaves])
         # steps is replicated (lockstep cond); traces are per-shard
         # partial sums — reduce on the host side of the boundary
         return (EngineState(*leaves), (sw, hi, pk, phi, ppk), steps[0],
@@ -1727,8 +1812,8 @@ def make_stepper(params: EngineParams, geom: EngineGeom, mesh=None,
             (st, ql, sw, hi, pk, phi, ppk, cur, _, _, steps, _, lcnt,
              wsum, aq, ri, rd, rr, rn, ra, rt) = jax.lax.while_loop(
                 cond, body, carry)
-            return (tuple(leaf[None] for leaf in st), ql[None], sw[None],
-                    hi[None], pk[None], phi[None], ppk[None],
+            return (tuple(leaf[None] for leaf in st[:nleaves]), ql[None],
+                    sw[None], hi[None], pk[None], phi[None], ppk[None],
                     steps[None], lcnt[None], wsum[None], aq[None],
                     ri[None], rd[None], rr[None], rn[None], ra[None],
                     rt[None], cur[None])
@@ -1762,7 +1847,7 @@ def make_stepper(params: EngineParams, geom: EngineGeom, mesh=None,
             jnp.asarray(budget, jnp.int32), jnp.asarray(pend_q),
             jnp.asarray(pend_arr, jnp.int32),
             jnp.asarray(cursor, jnp.int32), jnp.asarray(t0, jnp.int32),
-            *entry, *state)
+            *entry, *state[:nleaves])
         # steps is replicated (lockstep cond); cursors are replicated
         # too on the fan-out path (gsum'd) but per-shard when routed;
         # live/width traces are per-shard partial sums; the admit/evict

@@ -143,6 +143,7 @@ def stream_summary(stats) -> dict:
         if getattr(stats, "prefetch_issued", 0) else 0.0,
         "resident_fraction": round(
             float(getattr(stats, "resident_fraction", 1.0)), 4),
+        "stall_boundaries_max": getattr(stats, "stall_boundaries_max", 0),
         # live index (core/live.py): delta_hits counts result rows
         # answered from the append-only delta segment, tombstoned the
         # deletes applied during the run, epoch_swaps the background

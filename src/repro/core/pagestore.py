@@ -8,10 +8,12 @@ version of that: the per-shard vector pages (``consts["db"]`` /
 fixed-capacity **frame buffer** of ``device_pages`` pages per shard is
 the only device-resident copy. A translation table ``ttab`` (shape
 ``(S, NP)``, logical/physical page -> device frame, ``-1`` when not
-resident) is handed to the engine through ``consts``; the phase-B
-distance read goes through it (``KernelBackend.translated_item_
-distances``), and a non-resident page stalls its owner queries for the
-round (masked merge, retried next round) instead of reading garbage.
+resident) is handed to the engine through ``consts``; the round's
+distance read goes through it (``engine._fd_distance_flat``), and a
+row with a lane on a non-resident page stalls for the round (masked
+merge, retried next round) instead of reading garbage. The row keeps
+the distances it did read, so it needs only one of its pages resident
+at a time, and merges once every lane has its distance.
 
 Residency is managed **only at round-chunk boundaries**, on the host:
 
@@ -22,9 +24,14 @@ Residency is managed **only at round-chunk boundaries**, on the host:
    into its reserved frames (the ``device_put`` ran while the chunk
    computed, so the transfer is already overlapped; the scatter donates
    the frame buffer, keeping device memory flat).
-3. *demand* — fetch every page the chunk missed that is still not
-   resident, evicting clock victims. This is the synchronous, on-
-   critical-path tier: misses already cost stall rounds.
+3. *demand* — fetch the pages the chunk missed that are still not
+   resident, evicting clock victims, in descending order of the wait
+   key of the oldest row that missed each (``engine._wait_keys``), as
+   long as a shard has an unpinned frame. The oldest stalled row leads
+   every shard's order, so it gains at least one lane a boundary until
+   its round merges: no row starves, whatever ``device_pages`` is.
+   This is the synchronous, on-critical-path tier: misses already cost
+   stall rounds.
 4. *stage* — rank non-resident pages by the speculation signal (one-
    step traversal lookahead over the pool's candidate lists: adjacency
    neighbors weight 1, speculative prefetch-list neighbors weight
@@ -56,12 +63,6 @@ from repro.core.traversal import ID_SENTINEL
 # ID_SENTINEL is a device scalar; comparing host arrays against it would
 # promote the whole predictor to traced jax ops (and warn on float64)
 _SENTINEL = int(ID_SENTINEL)
-
-# A boundary that demanded pages but could not install a single one
-# (every frame pinned or reserved) makes no progress; the owning
-# queries would stall forever. This many consecutive no-progress
-# boundaries is a configuration error, not a transient.
-_NO_PROGRESS_LIMIT = 256
 
 
 def _pow2_pad(n: int) -> int:
@@ -154,8 +155,7 @@ class PageStore:
         self.hand = np.zeros((self.S,), np.int64)
         self.by_prefetch = np.zeros((self.S, self.P_dev), bool)
         self.reserved = np.zeros((self.S, self.P_dev), bool)
-        self._staged = None          # (meta, sidx, fidx, pay_db, pay_vn)
-        self._no_progress = 0
+        self._staged = None          # (meta, device payload)
 
         self.page_hits = 0
         self.page_misses = 0
@@ -165,6 +165,15 @@ class PageStore:
 
         self.frames = jnp.asarray(self.cold_db[:, :self.P_dev])
         self.vnf = jnp.asarray(self.cold_vn[:, :self.P_dev])
+        # compile the scatter at every payload size a boundary can send
+        # (a power of two up to every frame), so none compiles while
+        # serving; all-hole payloads leave the frames as they are
+        u = 1
+        while True:
+            self._scatter(self._pad_payload([], u))
+            if u >= self.S * self.P_dev:
+                break
+            u *= 2
 
     # -- geometry (numpy ports of EngineGeom's jnp arithmetic) ----------
     def _owner(self, vid):
@@ -234,37 +243,28 @@ class PageStore:
                 for s in range(self.S) for f in range(self.P_dev)
                 if self.frame_page[s, f] >= 0]
         if rows:
-            sidx, fidx, pay_db, pay_vn = self._push_payload(rows)
-            self.frames, self.vnf = _scatter_frames(
-                self.frames, self.vnf, sidx, fidx, pay_db, pay_vn,
-                pdev=self.P_dev)
+            self._scatter(self._push_payload(rows))
         return self.device_view()
 
-    def boundary(self, touch, miss, cand_i, cand_e, done):
+    def boundary(self, touch, miss, wait, cand_i, cand_e, done):
         """Process one round-chunk boundary; returns consts overrides.
 
         ``touch`` / ``miss``: (S, NP) bool bitmaps accumulated by the
-        engine since the last boundary. ``cand_i`` / ``cand_e`` /
-        ``done``: the pool state the predictor looks ahead from.
+        engine since the last boundary; ``wait``: (S, NP) the largest
+        wait key of a row that missed each page. ``cand_i`` /
+        ``cand_e`` / ``done``: the pool state the predictor looks ahead
+        from.
         """
         touch = np.asarray(touch)
         miss = np.asarray(miss)
+        wait = np.asarray(wait)
         pinned = np.zeros((self.S, self.P_dev), bool)
 
         self._note(touch)
         self.page_misses += int(miss.sum())
         self._commit(pinned)
         demand_s = np.zeros((self.S,), np.int64)
-        installed = self._demand(miss, pinned, demand_s)
-        if miss.any() and not installed:
-            self._no_progress += 1
-            if self._no_progress >= _NO_PROGRESS_LIMIT:
-                raise RuntimeError(
-                    "tiered page store made no demand-fetch progress for "
-                    f"{_NO_PROGRESS_LIMIT} boundaries (device_pages too "
-                    "small for the per-boundary working set)")
-        else:
-            self._no_progress = 0
+        self._demand(miss, wait, pinned, demand_s)
         if self.prefetch:
             self._stage(np.asarray(cand_i), np.asarray(cand_e),
                         np.asarray(done), pinned, demand_s)
@@ -283,11 +283,9 @@ class PageStore:
     def _commit(self, pinned):
         if self._staged is None:
             return
-        meta, sidx, fidx, pay_db, pay_vn = self._staged
+        meta, payload = self._staged
         self._staged = None
-        self.frames, self.vnf = _scatter_frames(
-            self.frames, self.vnf, sidx, fidx, pay_db, pay_vn,
-            pdev=self.P_dev)
+        self._scatter(payload)
         for s, page, f in meta:
             old = self.frame_page[s, f]
             if old >= 0:
@@ -321,10 +319,17 @@ class PageStore:
         self.by_prefetch[s, f] = False
         self.ref[s, f] = True
 
+    def _scatter(self, payload):
+        self.frames, self.vnf = _scatter_frames(
+            self.frames, self.vnf, *payload, pdev=self.P_dev)
+
     def _push_payload(self, rows):
-        """rows: list of (s, page, f). Builds a pow2-padded payload and
-        scatters it (holes at fidx == P_dev drop)."""
-        u = _pow2_pad(len(rows))
+        """rows: list of (s, page, f). The pow2-padded payload on the
+        device (holes at fidx == P_dev drop when scattered)."""
+        return self._pad_payload(rows, _pow2_pad(len(rows)))
+
+    def _pad_payload(self, rows, u):
+        """``rows`` in a payload of ``u`` slots, the rest holes."""
         sidx = np.zeros((u,), np.int32)
         fidx = np.full((u,), self.P_dev, np.int32)
         pay_db = np.zeros((u, self.P, self.d), self.cold_db.dtype)
@@ -336,10 +341,11 @@ class PageStore:
         return (jax.device_put(sidx), jax.device_put(fidx),
                 jax.device_put(pay_db), jax.device_put(pay_vn))
 
-    def _demand(self, miss, pinned, demand_s):
+    def _demand(self, miss, wait, pinned, demand_s):
         rows = []
         for s in range(self.S):
-            for page in np.nonzero(miss[s] & (self.ttab[s] < 0))[0]:
+            pages = np.flatnonzero(miss[s] & (self.ttab[s] < 0))
+            for page in pages[np.argsort(-wait[s, pages], kind="stable")]:
                 f = self._victim(s, pinned)
                 if f < 0:
                     break
@@ -347,14 +353,9 @@ class PageStore:
                 pinned[s, f] = True
                 demand_s[s] += 1
                 rows.append((s, int(page), f))
-        if not rows:
-            return False
-        sidx, fidx, pay_db, pay_vn = self._push_payload(rows)
-        self.frames, self.vnf = _scatter_frames(
-            self.frames, self.vnf, sidx, fidx, pay_db, pay_vn,
-            pdev=self.P_dev)
-        self.demand_fetches += len(rows)
-        return True
+        if rows:
+            self._scatter(self._push_payload(rows))
+            self.demand_fetches += len(rows)
 
     def _stage(self, cand_i, cand_e, done, pinned, demand_s):
         """Score-guided staging: a speculative page may only displace a
@@ -369,10 +370,13 @@ class PageStore:
         speculative budget backs off by that count — under thrash
         (working set >> frames) speculation adds churn without adding
         hits, and the throttle shuts it off exactly there."""
+        buds = self.budget - 2 * demand_s
+        if (buds <= 0).all():
+            return
         score = self._predict(cand_i, cand_e, done)
         meta = []
         for s in range(self.S):
-            bud = self.budget - 2 * int(demand_s[s])
+            bud = int(buds[s])
             if bud <= 0:
                 continue
             sc = score[s].copy()
@@ -396,7 +400,7 @@ class PageStore:
                 meta.append((s, page, int(f)))
         if not meta:
             return
-        self._staged = (meta, *self._push_payload(meta))
+        self._staged = (meta, self._push_payload(meta))
         self.prefetch_issued += len(meta)
 
     def _predict(self, cand_i, cand_e, done):

@@ -104,12 +104,6 @@ from repro.utils import BIG_DIST, bloom_insert
 INVALID = -1
 _SENTINEL = int(ID_SENTINEL)    # host mirror (module scope: no per-call sync)
 
-# tiered store: consecutive no-round-progress chunk boundaries for one
-# live row before the scheduler declares a livelock (the round's page
-# working set cannot fit the device cache, so demand fetches thrash
-# forever). A legitimate page stall clears at the next boundary.
-_LIVELOCK_BOUNDARIES = 256
-
 
 @dataclasses.dataclass
 class SpecController:
@@ -331,6 +325,11 @@ class StreamStats:
                               # tiered store: device frames / logical
                               # pages per shard (1.0 = fully resident
                               # or no tiered store)
+    stall_boundaries_max: int = 0
+                              # tiered store: the most consecutive chunk
+                              # boundaries any row of the call waited
+                              # without merging a round (on pages, or on
+                              # a fault-stalled shard)
     delta_hits: int = 0       # live index: retired result entries
                               # served from the delta segment
     tombstoned: int = 0       # live index: deletes applied during the
@@ -407,10 +406,6 @@ class StreamScheduler:
             # this view current as residency changes
             consts = dict(consts)
             consts.update(pagestore.device_view())
-            # livelock watch: per-slot count of consecutive boundaries
-            # with no round progress (see the boundary hook)
-            self._stall_rounds_prev = None
-            self._stall_count = None
         elif params.store_pages > 0:
             raise ValueError(
                 "params.store_pages > 0 needs a PageStore (pass "
@@ -635,6 +630,26 @@ class StreamScheduler:
                 self.controller.reset_rows(dead_rows)
         return state, qbuf, stall
 
+    def _page_boundary(self, state, steps, watch):
+        """The tiered store's chunk boundary, in the ``search.pages``
+        span: fold the chunk's touch/miss bitmaps into residency, commit
+        the payload staged at the previous boundary (its device_put
+        overlapped this chunk's compute), demand-fetch the misses oldest
+        waiting row first, stage the next speculative fetch set, and
+        refresh the consts view the next dispatch traces against.
+        Returns the state with its bitmaps cleared."""
+        (touch, miss, wait, cand_i, cand_e, done, rounds,
+         age) = jax.device_get(
+            (state.page_touch, state.page_miss, state.page_wait,
+             state.cand_i, state.cand_e, state.done, state.rounds,
+             state.age))
+        self.consts.update(self.pagestore.boundary(
+            touch, miss, wait, cand_i, cand_e, done))
+        watch.update(done, rounds, age, steps)
+        pz = jnp.zeros_like(state.page_touch)
+        return state._replace(page_touch=pz, page_miss=pz,
+                              page_wait=jnp.zeros_like(state.page_wait))
+
     def _warmup(self, state, qbuf, pend=None):
         """Compile the dispatch path actually used by :meth:`run` —
         admit/run_chunk/retire, or run_chunk_admit/retire when ``pend``
@@ -773,6 +788,7 @@ class StreamScheduler:
                          jnp.full((ring,), NEVER, jnp.int32))
         with spans.span(spans.WARMUP, call=phases.call):
             compile_s = self._warmup(state, qbuf, warm_pend)
+        watch = _StallWatch()
         owner = np.full((S, Qs), INVALID, np.int64)   # slot -> qid
         admit_t = np.zeros((S, Qs), np.int64)
         admit_wall = np.zeros((S, Qs), np.float64)
@@ -941,6 +957,9 @@ class StreamScheduler:
                 # the chunk boundary's one sync: everything else below
                 # transfers lazily (and batched) only if needed
                 steps = int(jax.device_get(steps))
+                if self.pagestore is not None and steps:
+                    phases(spans.PAGES, chunk=dispatches)
+                    state = self._page_boundary(state, steps, watch)
                 phases(spans.ACCOUNT, chunk=dispatches)
                 dispatches += 1
                 now_wall = time.perf_counter()
@@ -1042,50 +1061,13 @@ class StreamScheduler:
                                            stop_on_finish, dynamic=dyn)
                 phases(spans.SYNC, chunk=dispatches)
                 steps = int(jax.device_get(steps))    # host sync point
+                if self.pagestore is not None and steps:
+                    phases(spans.PAGES, chunk=dispatches)
+                    state = self._page_boundary(state, steps, watch)
                 phases(spans.ACCOUNT, chunk=dispatches)
                 dispatches += 1
             t += steps
             stepped += steps
-            if self.pagestore is not None and steps:
-                # -- tiered-store boundary: fold the chunk's touch/miss
-                # bitmaps into residency, commit the payload staged at
-                # the previous boundary (its device_put overlapped this
-                # chunk's compute), demand-fetch the misses, and stage
-                # the next speculative fetch set; then refresh the
-                # consts view the next dispatch traces against
-                (touch, miss, cand_i, cand_e, bdone, ra) = jax.device_get(
-                    (state.page_touch, state.page_miss, state.cand_i,
-                     state.cand_e, state.done, state.rounds))
-                upd = self.pagestore.boundary(
-                    touch, miss, cand_i, cand_e, bdone)
-                self.consts.update(upd)
-                pz = jnp.zeros_like(state.page_touch)
-                state = state._replace(page_touch=pz, page_miss=pz)
-                # livelock watch: when one round's page working set
-                # exceeds the cache, every boundary's demand installs
-                # evict pages the same round still needs — fetches
-                # happen (so the store's own no-progress guard never
-                # fires) but the round never completes. A live row
-                # whose round counter is frozen across this many
-                # consecutive boundaries is that configuration error
-                # (a legitimate stall clears at the next boundary's
-                # demand fetch), not a transient.
-                dn = bdone
-                if self._stall_count is None:
-                    self._stall_count = np.zeros(ra.shape, np.int64)
-                else:
-                    stuck = ~dn & (ra == self._stall_rounds_prev)
-                    self._stall_count = np.where(
-                        stuck, self._stall_count + 1, 0)
-                    if (self._stall_count >= _LIVELOCK_BOUNDARIES).any():
-                        raise RuntimeError(
-                            "tiered page store livelock: a query made "
-                            f"no round progress for {_LIVELOCK_BOUNDARIES}"
-                            " consecutive chunk boundaries — "
-                            "device_pages is smaller than a single "
-                            "round's page working set on its shard; "
-                            "raise --device-pages")
-                self._stall_rounds_prev = ra
             if self.controller is not None:
                 self.controller.store(spec_state)
             # one batched transfer for the chunk's accounting: the
@@ -1165,12 +1147,36 @@ class StreamScheduler:
                              if self.pagestore is not None else 0),
             resident_fraction=(self.pagestore.resident_fraction
                                if self.pagestore is not None else 1.0),
+            stall_boundaries_max=watch.most,
             delta_hits=(self.live.delta_hits - live_hit0
                         if self.live is not None else 0),
             tombstoned=(self.live.deletes - live_del0
                         if self.live is not None else 0),
             epoch_swaps=epoch_swaps,
             swap_stall_rounds=swap_stall)
+
+
+class _StallWatch:
+    """The most consecutive chunk boundaries a row waited without
+    merging a round: a row live at two boundaries in a row, the same
+    query (it aged through the whole chunk), with its ``rounds``
+    unchanged, waited the chunk through."""
+
+    def __init__(self):
+        self.prev = None
+        self.count = None
+        self.most = 0
+
+    def update(self, done, rounds, age, steps):
+        if self.prev is None:
+            self.count = np.zeros(done.shape, np.int64)
+        else:
+            pdone, prounds, page = self.prev
+            waited = (~pdone & ~done & (age == page + steps)
+                      & (rounds == prounds))
+            self.count = np.where(waited, self.count + 1, 0)
+            self.most = max(self.most, int(self.count.max()))
+        self.prev = (done, rounds, age)
 
 
 def poisson_arrivals(rate: float, n: int, seed: int = 0) -> np.ndarray:

@@ -9,11 +9,13 @@ device sat idle.
 One call of the served entry (``scheduler.stream_search``) writes one
 ``search.call`` span, tiled by consecutive children: ``search.setup``
 (holding ``search.warmup``), then for each round-chunk dispatch
-``search.stage``, ``search.dispatch``, ``search.sync`` and
+``search.stage``, ``search.dispatch``, ``search.sync``, with a tiered
+page store ``search.pages`` (its chunk boundary: the bitmap and pool
+transfer, ``PageStore.boundary`` and the consts refresh), and
 ``search.account``, then ``search.finish``. Every span carries the
-call's ``call`` id; the four per-dispatch spans also carry ``chunk``,
-the dispatch's index within the call. The ids ride in the event's
-stats, not its name.
+call's ``call`` id; the per-dispatch spans also carry ``chunk``, the
+dispatch's index within the call. The ids ride in the event's stats,
+not its name.
 """
 from __future__ import annotations
 
@@ -27,6 +29,7 @@ WARMUP = "search.warmup"
 STAGE = "search.stage"
 DISPATCH = "search.dispatch"
 SYNC = "search.sync"
+PAGES = "search.pages"
 ACCOUNT = "search.account"
 FINISH = "search.finish"
 

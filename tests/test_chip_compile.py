@@ -98,46 +98,51 @@ def test_candidate_merge_compiles(one_chip, L, M):
         "tpu_custom_call") >= 2
 
 
-def _scheduler(shards, device_pages=0):
+def _scheduler(shards, device_pages=0, n=N, dim=96, degree=DEGREE,
+               slots=SLOTS):
     """A pallas-mode scheduler over a random graph at the smoke's shapes
     (the compile needs shapes, not a navigable graph)."""
     rng = np.random.default_rng(0)
-    db = rng.standard_normal((N, 96)).astype(np.float32)
-    adj = rng.integers(0, N, (N, DEGREE)).astype(np.int32)
+    db = rng.standard_normal((n, dim)).astype(np.float32)
+    adj = rng.integers(0, n, (n, degree)).astype(np.int32)
     geo = Geometry(num_shards=shards, page_size=PAGE, pages_per_block=4,
-                   dim=96)
+                   dim=dim)
     packed = pack_index(LUNCSR.from_adjacency(db, adj, geo),
-                        max_degree=DEGREE)
+                        max_degree=degree)
     consts, geom, entry = pack_for_engine(packed)
-    params = EngineParams.lossless(SearchParams(L=32, W=1, k=10), SLOTS,
-                                   DEGREE, kernel_mode="pallas")
+    params = EngineParams.lossless(SearchParams(L=32, W=1, k=10), slots,
+                                   degree, kernel_mode="pallas")
     store = None
     if device_pages:
         store = PageStore(consts, geom, device_pages, w_select=1)
         params = dataclasses.replace(params, store_pages=store.num_pages)
-    return StreamScheduler(consts, geom, params, entry, num_slots=SLOTS,
+    return StreamScheduler(consts, geom, params, entry, num_slots=slots,
                            round_chunk=CHUNK, pagestore=store)
 
 
-def _chunk_admit_args(sched):
+def _chunk_admit_args(sched, dim=96):
     """The in-jit admission chunk's arguments, as the scheduler's
     warmup passes them."""
-    state, qbuf = sched._fresh_pool(96)
-    spec_state, cfg, _ = sched._spec_inputs((sched.S, SLOTS))
-    pend = (np.zeros((PENDING, 96), np.float32),
+    state, qbuf = sched._fresh_pool(dim)
+    spec_state, cfg, _ = sched._spec_inputs((sched.S, sched.num_slots))
+    pend = (np.zeros((PENDING, dim), np.float32),
             np.zeros((PENDING,), np.int32))
     return (sched.consts, state, qbuf, spec_state, cfg, np.int32(1), pend,
             np.int32(PENDING), np.int32(0), sched.entry)
 
 
-@pytest.mark.parametrize("device_pages", [0, 16], ids=["resident",
-                                                      "half_tiered"])
-def test_pallas_stepper_compiles(one_chip, device_pages):
+@pytest.mark.parametrize("device_pages,shape", [
+    (0, {}), (16, {}),
+    (4, {"n": 8192, "dim": 100, "degree": 32, "slots": 32})],
+    ids=["resident", "half_tiered", "msturing100_quarter_tiered"])
+def test_pallas_stepper_compiles(one_chip, device_pages, shape):
     """engine_run_chunk_admit in pallas mode on one chip, S=8 shards:
-    the fully resident store and the tiered store at half residency."""
-    sched = _scheduler(8, device_pages)
+    the fully resident store, the tiered store at half residency, and
+    the ``msturing100-tiered`` cell's shapes (d=100, 4 of 16 frames)."""
+    sched = _scheduler(8, device_pages, **shape)
     compiled = _compile(sched.stepper.run_chunk_admit,
-                        _chunk_admit_args(sched), one_chip)
+                        _chunk_admit_args(sched, shape.get("dim", 96)),
+                        one_chip)
     # distance, proposal sort and candidate merge kernels
     assert compiled.as_text().count("tpu_custom_call") >= 3
 

@@ -1,6 +1,7 @@
-"""Tiered page store: full-residency bit-identity, eviction metadata
-consistency, stall accounting and prefetch-hit attribution
-(core/pagestore.py + the scheduler's chunk-boundary hook)."""
+"""Tiered page store: full-residency bit-identity, the resident answers
+at every residency, eviction metadata consistency, stall accounting and
+prefetch-hit attribution (core/pagestore.py + the scheduler's
+chunk-boundary hook)."""
 import dataclasses
 
 import numpy as np
@@ -10,7 +11,7 @@ from repro.core.engine import EngineParams, pack_for_engine
 from repro.core.graph import build_vamana
 from repro.core.luncsr import Geometry, LUNCSR, pack_index
 from repro.core.pagestore import PageStore
-from repro.core.ref_search import SearchParams
+from repro.core.ref_search import SearchParams, lockstep_search_batch
 from repro.core.scheduler import stream_search
 
 
@@ -147,15 +148,71 @@ def test_stall_accounting_stretches_clock_not_service(ds):
             r.service_rounds + r.stall_rounds
 
 
-def test_livelock_guard_raises(ds):
-    """A cache smaller than a single round's page working set can never
-    complete that round: every boundary's demand installs evict pages
-    the same round still needs. The scheduler must turn that into a
-    loud configuration error, not an infinite hang."""
-    _, _, packed = ds
-    consts, _, _ = pack_for_engine(packed)
-    with pytest.raises(RuntimeError, match="tiered page store"):
-        _run(ds, pagestore=_store(ds, 2, prefetch=False), store=True)
+@pytest.fixture(scope="module")
+def resident(ds):
+    return _run(ds)
+
+
+@pytest.mark.parametrize("frames", ["1", "2", "half", "all"])
+def test_tiered_stream_serves_the_resident_answers(ds, resident, frames):
+    """At every residency, down to one frame a shard, the tiered stream
+    returns the resident run's ids and distances, and each query works
+    the resident run's rounds: a stalled row keeps the distances it read
+    and merges once every lane has one, so no residency livelocks. The
+    vectors are integers, so a distance is exact whatever page tiles the
+    kernel groups it in, and the distances are compared bit for bit.
+    The longest wait at chunk boundaries is bounded by the rows' own
+    stall rounds."""
+    ref_i, ref_d, ref_st = resident
+    NP = _store(ds, 1).num_pages
+    D = {"1": 1, "2": 2, "half": NP // 2, "all": NP}[frames]
+    ids, dists, st = _run(ds, pagestore=_store(ds, D), store=True)
+    np.testing.assert_array_equal(ids, ref_i)
+    np.testing.assert_array_equal(dists, ref_d)
+    if D == NP:
+        assert st.stalls == 0 and st.stall_boundaries_max == 0
+    else:
+        assert st.stalls > 0
+    ref_srv = {r.qid: r.service_rounds for r in ref_st.results}
+    assert {r.qid: r.service_rounds for r in st.results} == ref_srv
+    assert st.stall_boundaries_max <= max(r.stall_rounds
+                                          for r in st.results)
+    if D <= 2:
+        assert st.stall_boundaries_max > 0
+
+
+def test_two_frames_serve_the_lockstep_answers(ds):
+    """Two frames a shard, far below a round's pages: every query gets
+    the numpy lockstep reference's ids and distances (integer vectors,
+    so exact), as the resident engine does."""
+    db, queries, packed = ds
+    adj, _ = build_vamana(db, r=8, alpha=1.2, seed=0)
+    sp = SearchParams(L=8, W=1, k=5)
+    ref_i, ref_d, _ = lockstep_search_batch(db, adj, queries,
+                                            packed.entry, sp)
+    ids, dists, st = _run(ds, pagestore=_store(ds, 2), store=True,
+                          spec=0)
+    assert st.stalls > 0
+    np.testing.assert_array_equal(ids, ref_i)
+    np.testing.assert_array_equal(dists, ref_d)
+
+
+def test_store_compiles_nothing_after_its_warm_up(ds):
+    """The store compiles its frame scatter at every payload size when
+    it is built, and a call of the served entry compiles its steppers:
+    a second call at partial residency, whose boundaries fetch payloads
+    of other sizes, compiles nothing."""
+    from repro.analysis.compile_guard import CompileGuard
+
+    _, queries, _ = ds
+    ps = _store(ds, 2)
+    _run(ds, pagestore=ps, store=True)
+    before = ps.counters()["demand_fetches"]
+    with CompileGuard() as guard:
+        _, _, st = _run(ds, pagestore=ps, store=True,
+                        arrivals=np.arange(len(queries)) * 3)
+    assert ps.counters()["demand_fetches"] > before and st.stalls > 0
+    assert guard.total == 0, guard.names
 
 
 # ---------------------------------------------------------------------------
@@ -190,7 +247,7 @@ def test_eviction_correctness(ds):
     miss = np.zeros((S, NP), bool)
     want = list(range(pdev, pdev + 3))        # 3 non-resident pages
     miss[0, want] = True
-    ps.boundary(touch, miss, *no_cands)
+    ps.boundary(touch, miss, np.zeros_like(miss, np.int32), *no_cands)
     _check_consistent(ps)
     assert (ps.ttab[0, want] >= 0).all()      # all demanded now resident
     assert ps.counters()["demand_fetches"] == 3
@@ -208,10 +265,34 @@ def test_eviction_correctness(ds):
     touch2[0, want[0]] = True
     miss2 = np.zeros((S, NP), bool)
     miss2[0, pdev + 3] = True                 # one more demand
-    ps.boundary(touch2, miss2, *no_cands)
+    ps.boundary(touch2, miss2, np.zeros_like(miss2, np.int32), *no_cands)
     _check_consistent(ps)
     assert ps.ttab[0, want[0]] >= 0, "touched page was evicted"
     assert ps.ttab[0, pdev + 3] >= 0
+
+
+@pytest.mark.parametrize("frames", [1, 2])
+def test_demand_serves_the_oldest_waiting_row_first(ds, frames):
+    """More pages missed than a shard has frames: the demand fetch
+    installs the pages in descending order of the wait key of the
+    oldest row that missed each, not in page order."""
+    _, _, packed = ds
+    consts, geom, _ = pack_for_engine(packed)
+    NP = consts["db"].shape[1]
+    ps = PageStore(consts, geom, frames, w_select=1, prefetch=False)
+    S = ps.S
+    no_cands = (np.full((S, 1, 4), -1, np.int32),
+                np.zeros((S, 1, 4), bool), np.ones((S, 1), bool))
+    miss = np.zeros((S, NP), bool)
+    wait = np.zeros((S, NP), np.int32)
+    pages = [NP - 3, NP - 2, NP - 1]
+    miss[0, pages] = True
+    wait[0, pages] = [1, 9, 4]
+    ps.boundary(np.zeros((S, NP), bool), miss, wait, *no_cands)
+    _check_consistent(ps)
+    resident = set(np.flatnonzero(ps.ttab[0] >= 0).tolist())
+    assert resident == set([NP - 2, NP - 1][:frames])
+    assert ps.counters()["demand_fetches"] == frames
 
 
 def test_prefetch_hit_counting_fixed_traversal(ds):
@@ -232,11 +313,12 @@ def test_prefetch_hit_counting_fixed_traversal(ds):
     no_cands = (np.full((S, 1, 4), -1, np.int32),
                 np.zeros((S, 1, 4), bool), np.ones((S, 1), bool))
     quiet = np.zeros((S, NP), bool)
+    nowait = np.zeros_like(quiet, np.int32)
 
-    ps.boundary(quiet, quiet, *no_cands)      # stages target
+    ps.boundary(quiet, quiet, nowait, *no_cands)  # stages target
     assert ps.counters()["prefetch_issued"] == 1
     assert ps.ttab[0, target] < 0             # staged, not yet resident
-    ps.boundary(quiet, quiet, *no_cands)      # commits target
+    ps.boundary(quiet, quiet, nowait, *no_cands)  # commits target
     _check_consistent(ps)
     f = ps.ttab[0, target]
     assert f >= 0 and ps.by_prefetch[0, f]
@@ -244,9 +326,9 @@ def test_prefetch_hit_counting_fixed_traversal(ds):
                                   ps.cold_db[0, target])
     touch = np.zeros((S, NP), bool)
     touch[0, target] = True
-    ps.boundary(touch, quiet, *no_cands)      # first use: one hit
+    ps.boundary(touch, quiet, nowait, *no_cands)  # first use: one hit
     assert ps.counters()["prefetch_hits"] == 1
-    ps.boundary(touch, quiet, *no_cands)      # reuse: no double count
+    ps.boundary(touch, quiet, nowait, *no_cands)  # reuse: no double count
     assert ps.counters()["prefetch_hits"] == 1
     assert ps.counters()["page_misses"] == 0
 

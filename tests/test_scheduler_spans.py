@@ -1,8 +1,9 @@
 """The served entry's host spans (``core/spans.py``), read back from a
 real profiler trace: one ``search.call`` per call of ``stream_search``,
-tiled by set-up, four spans per round-chunk dispatch and finish, every
-span carrying its call's id."""
+tiled by set-up, four spans per round-chunk dispatch (five with a tiered
+page store) and finish, every span carrying its call's id."""
 import collections
+import dataclasses
 import glob
 import os
 
@@ -15,12 +16,13 @@ from repro.core import spans
 from repro.core.engine import EngineParams, pack_for_engine
 from repro.core.graph import build_vamana
 from repro.core.luncsr import Geometry, LUNCSR, pack_index
+from repro.core.pagestore import PageStore
 from repro.core.ref_search import SearchParams
 from repro.core.scheduler import stream_search
 
 HOST_PLANE = "/host:CPU"
 PER_DISPATCH = (spans.STAGE, spans.DISPATCH, spans.SYNC, spans.ACCOUNT)
-CHILDREN = (spans.SETUP,) + PER_DISPATCH + (spans.FINISH,)
+CHILDREN = (spans.SETUP,) + PER_DISPATCH + (spans.PAGES, spans.FINISH)
 NAMES = set(CHILDREN) | {spans.CALL, spans.WARMUP}
 
 
@@ -62,19 +64,28 @@ def _covered(intervals):
     return total
 
 
-@pytest.mark.parametrize("injit,ring", [(True, 8), (False, 0)],
-                         ids=["injit-ring", "host-paced"])
-def test_spans_tile_each_call(index, tmp_path, injit, ring):
+@pytest.mark.parametrize("injit,ring,frames", [
+    (True, 8, 0), (False, 0, 0), (True, 8, 2), (False, 0, 2)],
+    ids=["injit-ring", "host-paced", "injit-tiered", "host-paced-tiered"])
+def test_spans_tile_each_call(index, tmp_path, injit, ring, frames):
     consts, geom, entry, queries = index
     params = EngineParams.lossless(SearchParams(L=12, W=1, k=5), 2,
                                    geom.max_degree)
+    kw = {}
+    if frames:
+        store = PageStore(consts, geom, frames, w_select=1)
+        params = dataclasses.replace(params, store_pages=store.num_pages)
+        kw["pagestore"] = store
     arrivals = np.random.default_rng(5).integers(0, 30, len(queries))
     with jax.profiler.trace(str(tmp_path)):
         stats = [stream_search(consts, geom, params, entry, queries,
                                num_slots=2, arrivals=arrivals,
                                round_chunk=4, injit_admit=injit,
-                               ring_capacity=ring)[2] for _ in range(2)]
+                               ring_capacity=ring, **kw)[2]
+                 for _ in range(2)]
     assert all(st.host_dispatches > 1 for st in stats)
+    if frames:
+        assert all(st.stalls > 0 for st in stats)
 
     by_call = collections.defaultdict(list)
     for sp in _spans(str(tmp_path)):
@@ -85,11 +96,22 @@ def test_spans_tile_each_call(index, tmp_path, injit, ring):
         count = collections.Counter(name for name, *_ in got)
         for name in (spans.CALL, spans.SETUP, spans.WARMUP, spans.FINISH):
             assert count[name] == 1, (name, count)
-        for name in PER_DISPATCH:
+        for name in PER_DISPATCH + ((spans.PAGES,) if frames else ()):
             assert count[name] == st.host_dispatches, (name, count)
             chunks = sorted(ids["chunk"] for n, _, _, ids in got
                             if n == name)
             assert chunks == list(range(st.host_dispatches))
+        if frames:
+            # the store's boundary lies between the chunk's sync and
+            # its account
+            at = {(n, ids["chunk"]): (s, e) for n, s, e, ids in got
+                  if n in (spans.SYNC, spans.PAGES, spans.ACCOUNT)}
+            for c in range(st.host_dispatches):
+                assert (at[spans.SYNC, c][1] <= at[spans.PAGES, c][0]
+                        and at[spans.PAGES, c][1]
+                        <= at[spans.ACCOUNT, c][0])
+        else:
+            assert count[spans.PAGES] == 0
         first = {name: (s, e) for name, s, e, _ in got}
         c0, c1 = first[spans.CALL]
         assert all(c0 <= s and e <= c1 for _, s, e, _ in got)
