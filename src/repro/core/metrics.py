@@ -107,6 +107,10 @@ def stream_summary(stats) -> dict:
         "dispatches_per_query": round(dispatches / n, 3) if n else 0.0,
         "rounds_per_dispatch": round(
             stats.total_rounds / dispatches, 3) if dispatches else 0.0,
+        # the run-ahead boundary order: chunks launched before the
+        # previous one was synced, and chunks that ran zero rounds
+        "inflight_dispatches": getattr(stats, "inflight_dispatches", 0),
+        "empty_dispatches": getattr(stats, "empty_dispatches", 0),
         "compile_s": round(float(getattr(stats, "compile_s", 0.0)), 3),
         "injit_admit": bool(getattr(stats, "injit_admit", False)),
         "pages_unique": stats.pages_unique,

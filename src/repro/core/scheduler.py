@@ -49,7 +49,10 @@ per-boundary admit traces, and the host replays those traces at the
 chunk boundary to reconstruct ``owner``/``admit_t``/``retire_round``
 (``retire_round = admit_round + rounds``) bit-exactly; per-round
 live-count/width traces reconstruct occupancy and speculation traces
-per round, not per boundary.
+per round, not per boundary. Where the next chunk needs nothing of that
+replay, the host enqueues it from the previous chunk's device outputs
+first and replays while it runs (:meth:`StreamScheduler.run`'s boundary
+order).
 
 What remains host-side: **result emission** (QueryResult records are
 materialized from the traces at chunk boundaries), the **frozen-mode
@@ -341,9 +344,103 @@ class StreamStats:
                               # swaps — rows whose whole frontier died
                               # with the old epoch restart from the new
                               # entry (translated rows discard nothing)
+    inflight_dispatches: int = 0
+                              # chunks launched before the previous
+                              # chunk was synced (the run-ahead order)
+    empty_dispatches: int = 0
+                              # chunks that ran zero rounds: a call run
+                              # ahead ends with one, and pays one at
+                              # each idle gap in its arrivals
 
     def by_qid(self):
         return {r.qid: r for r in self.results}
+
+
+def _clock_after(steps, t0):
+    """The serving round a chunk ends at, ``t0 + steps``, computed on
+    the device: the next chunk's ``t0`` when the host runs ahead."""
+    return steps + t0
+
+
+class _Book:
+    """The host's replay of the slot pool over one call: which query
+    holds each (shard, slot) row and since which round, the serving
+    clock, and what the call has retired and stepped so far."""
+
+    def __init__(self, arrivals, shape, map_result=None):
+        self.arrivals = arrivals
+        self.map_result = map_result        # live index: internal -> external ids
+        self.owner = np.full(shape, INVALID, np.int64)   # slot -> qid
+        self.admit_t = np.zeros(shape, np.int64)
+        self.admit_wall = np.zeros(shape, np.float64)
+        # live index: serving age carried across swap restarts (zeroed
+        # at every seat; identically zero without swaps)
+        self.age_base = np.zeros(shape, np.int64)
+        self.rounds_base = np.zeros(shape, np.int64)
+        self.t = 0                          # serving clock, rounds
+        self.stepped = 0                    # engine rounds run
+        self.results: list[QueryResult] = []
+        self.occ_trace: list[int] = []
+        self.spec_trace: list[float] = []
+
+    def seat(self, s, r, qid, t, wall):
+        self.owner[s, r] = qid
+        self.admit_t[s, r] = t
+        self.admit_wall[s, r] = wall
+        self.age_base[s, r] = 0
+        self.rounds_base[s, r] = 0
+
+    def retire_row(self, s, r, ids, dists, age, rounds, n_dist, trunc,
+                   wall):
+        """Emit row (s, r)'s query and free the row. Exact even when the
+        finish was mid-chunk: the row aged ``age`` consecutive serving
+        rounds from admission (== ``rounds`` worked unless a fault or a
+        page miss stalled it)."""
+        ids, dists = ids.copy(), dists.copy()
+        if self.map_result is not None:
+            ids, dists = self.map_result(ids, dists)
+        qid = int(self.owner[s, r])
+        age = int(self.age_base[s, r] + age)
+        rounds = int(self.rounds_base[s, r] + rounds)
+        self.results.append(QueryResult(
+            qid=qid, ids=ids, dists=dists,
+            arrival_round=int(self.arrivals[qid]),
+            admit_round=int(self.admit_t[s, r]),
+            retire_round=int(self.admit_t[s, r]) + age,
+            service_rounds=rounds, n_dist=int(n_dist),
+            wall_latency_s=wall - self.admit_wall[s, r],
+            truncated=bool(trunc), stall_rounds=age - rounds))
+        self.owner[s, r] = INVALID
+        self.age_base[s, r] = 0
+        self.rounds_base[s, r] = 0
+
+
+@dataclasses.dataclass
+class _Chunk:
+    """One launched round chunk: its index within the call, its outputs
+    (device arrays until the host accounts it), and what the host needs
+    to replay it."""
+
+    cid: int
+    launch_wall: Optional[float]
+    state: object
+    qbuf: object
+    spec_state: tuple
+    steps: object
+    live_cnt: object
+    width_sum: object
+    trace: tuple = ()         # in-jit: admit_qidx and the six ret_* traces
+    cur: object = None        # in-jit: the cursor after the chunk
+    t0: object = None         # in-jit: the serving round at entry
+    seat_qid: np.ndarray = None   # pending index -> qid ((S, cap) routed)
+    fin: tuple = None         # the pool's (ids, dists), enqueued ahead
+
+    def reads(self):
+        """Every device value the account reads."""
+        st = self.state
+        return (self.live_cnt, self.width_sum,
+                (st.done, st.rounds, st.n_dist, st.age, st.truncated),
+                self.trace, self.cur, self.fin)
 
 
 class StreamScheduler:
@@ -664,15 +761,17 @@ class StreamScheduler:
             # compile on the real staged queue (its shape fixes the
             # trace) with an exhausted cursor and an all-parked pool:
             # the while_loop compiles but runs zero rounds, admitting
-            # and mutating nothing — outputs are discarded anyway
+            # and mutating nothing — outputs are discarded anyway. The
+            # cursor and clock go in as int32 scalars, the type both a
+            # host value and a chunk's device output have in the window
             if np.ndim(pend[1]) == 2:   # routed: per-shard cursors
                 done_cur = jnp.full((pend[1].shape[0],),
                                     pend[1].shape[1], jnp.int32)
             else:
-                done_cur = int(pend[1].shape[0])
+                done_cur = np.int32(pend[1].shape[0])
             out = self.stepper.run_chunk_admit(
                 self.consts, state, qbuf, spec_state, cfg, 1, pend,
-                done_cur, 0, self.entry, dynamic=dyn)
+                done_cur, np.int32(0), self.entry, dynamic=dyn)
             ids, dists, _ = self._retire(state, qbuf)
             if self.live is not None:
                 # epoch-swap restarts admit host-side even on the
@@ -682,7 +781,8 @@ class StreamScheduler:
                 astate, _ = self.stepper.admit(state, qbuf, zmask, qbuf,
                                                *self.entry)
                 jax.block_until_ready(astate.done)
-            jax.block_until_ready((out[0].done, out[13], ids, dists))
+            jax.block_until_ready((out[0].done, out[13], ids, dists,
+                                   _clock_after(out[3], np.int32(0))))
             return time.perf_counter() - t0
         zmask = jnp.zeros((S, Qs), bool)
         wstate, wq = self.stepper.admit(state, qbuf, zmask, qbuf,
@@ -694,6 +794,97 @@ class StreamScheduler:
         ids, dists, _ = self._retire(wstate, wq)
         jax.block_until_ready((out[0].done, ids, dists))
         return time.perf_counter() - t0
+
+    def _dispatch_admit(self, cid, state, qbuf, spec, pend, cursor, t0,
+                        seat_qid, ahead):
+        """Enqueue one in-jit admission chunk. ``cursor`` and ``t0`` are
+        int32 scalars: host values, or the previous chunk's device
+        outputs (one compiled program serves both). With ``ahead`` the
+        next chunk is enqueued before this one is accounted, so the
+        pool's retire is enqueued here, ahead of the next chunk on the
+        device, and the host copies of everything the account reads
+        start as soon as this chunk ends."""
+        spec_state, cfg, dyn = spec
+        launch_wall = time.perf_counter()
+        (state, qbuf, spec_state, steps, live_cnt, width_sum, *trace,
+         cur) = self.stepper.run_chunk_admit(
+            self.consts, state, qbuf, spec_state, cfg, self.round_chunk,
+            pend, cursor, t0, self.entry, dynamic=dyn)
+        ch = _Chunk(cid, launch_wall, state, qbuf, spec_state, steps,
+                    live_cnt, width_sum, tuple(trace), cur, t0, seat_qid)
+        if ahead:
+            ch.fin = self._retire(state, qbuf)[:2]
+            for a in jax.tree_util.tree_leaves((steps, ch.reads())):
+                a.copy_to_host_async()
+        return ch
+
+    def _enqueue_next(self, ch, cid, pend, phases):
+        """Stage and enqueue the chunk after ``ch`` from ``ch``'s device
+        outputs alone: the pool, the query buffer, the widths, the
+        cursor and the clock ``t0 + steps``. The host has not accounted
+        ``ch`` yet; the device decides every seat and retirement."""
+        phases(spans.STAGE, chunk=cid)
+        spec = (ch.spec_state,) + self._spec_inputs((self.S,
+                                                     self.num_slots))[1:]
+        t0 = _clock_after(ch.steps, ch.t0)
+        phases(spans.DISPATCH, chunk=cid)
+        return self._dispatch_admit(cid, ch.state, ch.qbuf, spec, pend,
+                                    ch.cur, t0, ch.seat_qid, True)
+
+    def _account(self, book, ch, steps):
+        """The host's side of one synced chunk, the same in both
+        boundary orders: replay the in-jit admit traces (emit each
+        finished row a seat evicted, record the seat), advance the
+        serving clock, extend the occupancy and width traces, and retire
+        the rows the chunk left finished. One batched transfer brings
+        what it reads. Returns the chunk's cursor on the host (None for
+        a host-paced chunk)."""
+        (live_cnt, width_sum, (done, rounds, n_dist, age, trunc), trace,
+         cur, fin) = jax.device_get(ch.reads())
+        if trace:
+            now_wall = time.perf_counter()
+            (admit_qidx, ret_i, ret_d, ret_rounds, ret_ndist, ret_age,
+             ret_trunc) = trace
+            routed = ch.seat_qid.ndim == 2
+            for j in range(steps):
+                for s, r in np.argwhere(admit_qidx[j] >= 0):
+                    if book.owner[s, r] != INVALID:
+                        # the seated query evicted a finished row —
+                        # emit it from the boundary-j capture (bit-
+                        # identical to a host-side retire on that round)
+                        book.retire_row(
+                            s, r, ret_i[j, s, r], ret_d[j, s, r],
+                            ret_age[j, s, r], ret_rounds[j, s, r],
+                            ret_ndist[j, s, r], ret_trunc[j, s, r],
+                            now_wall)
+                    # routed: pidx indexes shard s's own queue
+                    p = admit_qidx[j, s, r]
+                    book.seat(s, r, int(ch.seat_qid[s, p] if routed
+                                        else ch.seat_qid[p]),
+                              book.t + j, ch.launch_wall)
+        book.t += steps
+        book.stepped += steps
+        if self.controller is not None:
+            self.controller.store(ch.spec_state)
+        live_cnt = live_cnt[:steps]
+        book.occ_trace.extend(int(c) for c in live_cnt)
+        book.spec_trace.extend(ws / c for ws, c in
+                               zip(width_sum[:steps],
+                                   np.maximum(live_cnt, 1)))
+        # -- retire finished rows (the chunk already parked rows that
+        # hit the per-query round cap, at the exact round boundary the
+        # per-round scheduler would have)
+        done_rows = (book.owner != INVALID) & done
+        if done_rows.any():
+            if fin is None:
+                fin = jax.device_get(self._retire(ch.state, ch.qbuf)[:2])
+            out_i, out_d = fin
+            now_wall = time.perf_counter()
+            for s, r in np.argwhere(done_rows):
+                book.retire_row(s, r, out_i[s, r], out_d[s, r], age[s, r],
+                                rounds[s, r], n_dist[s, r], trunc[s, r],
+                                now_wall)
+        return cur
 
     def run(self, queries: np.ndarray,
             arrivals: Optional[np.ndarray] = None,
@@ -712,7 +903,20 @@ class StreamScheduler:
         own arrival-ordered queue independently, and a shard with no
         routed work stays parked — the two-tier serving discipline
         (``routed_stream_search`` fans queries into per-shard legs and
-        fuses their top-k)."""
+        fuses their top-k).
+
+        **Boundary order.** Where every input of the next chunk is a
+        device value — in-jit admission from a queue staged once, with
+        no host hook between chunks — the host enqueues chunk j+1 from
+        chunk j's outputs and then syncs and accounts chunk j while
+        j+1 runs (with a page store, after chunk j's page boundary: the
+        store must see j's misses first). Otherwise (routed, host-paced,
+        a ring restaged every chunk, the shed policy, a live index, a
+        host speculation controller) the host accounts chunk j before
+        it stages j+1. The host learns that the call is done only when
+        it accounts the last retirement, so a call run ahead ends with
+        one chunk of zero rounds, and so does each idle gap in its
+        arrivals (``StreamStats.empty_dispatches``)."""
         if phases is None:
             call = spans.next_call()
             with spans.span(spans.CALL, call=call), \
@@ -730,20 +934,25 @@ class StreamScheduler:
                              "serve per-shard target_shards")
         S, Qs = self.S, self.num_slots
         K = self.round_chunk
-        stepped = 0                                   # engine rounds run
         idle = 0                                      # empty-pool rounds
         dispatches = 0                                # run_chunk launches
+        inflight = 0                  # launched before the previous sync
+        empty = 0                     # chunks that ran zero rounds
         injit = self.injit_admit and N > 0
-        # bounded admission ring (flat in-jit path only): the device
-        # pending queue is a sliding window of at most `ring` staged
+        # bounded admission ring (flat in-jit path only): a queue longer
+        # than the ring slides through a window of `ring` staged
         # queries, restaged at each chunk boundary — memory stays flat
-        # however long the stream is. ring=0 keeps the stage-everything
-        # path (and its results) verbatim.
+        # however long the stream is. A queue that fits is staged once.
         ring = self.ring_capacity if injit and not routed else 0
+        restage = N > ring > 0
+        ahead = (injit and not routed and not restage
+                 and self.live is None and self.controller is None
+                 and self.overload == "block")
         staged: list[int] = []        # ring window: qids, arrival order
         shed_qids: list[int] = []     # rejected by the shed policy
         stream_pos = 0                # ring cursor into `order`
         pend = None
+        seat_qid = order              # pending index -> qid
         if routed:
             # per-shard admission queues, staged once via the Allocator
             # discipline (dispatch.py bucket scatter) in arrival order:
@@ -770,32 +979,36 @@ class StreamScheduler:
                     jnp.asarray(arrivals[order], jnp.int32), S, cap,
                     fill=np.int32(2**31 - 1))))
             next_qs = np.zeros(S, np.int64)       # per-shard cursors
+            seat_qid = legidx
             if injit:
                 pend = (scatter_to_buckets(
                     dest, rank, valid, jnp.asarray(queries[order]), S,
                     cap), jnp.asarray(arr_by_shard))
-        elif injit and not ring:
-            # device-side pending queue, staged once in admission order
-            pend = (jnp.asarray(queries[order]),
-                    jnp.asarray(arrivals[order], jnp.int32))
+        elif injit and not restage:
+            # device-side pending queue, staged once in admission order,
+            # padded to the ring's length: its fixed shape lets one
+            # compile serve every call. NEVER-padded tails sort after
+            # every real arrival for the in-jit searchsorted.
+            rows = max(N, ring)
+            wq = np.zeros((rows, d), np.float32)
+            wa = np.full((rows,), NEVER, np.int32)
+            wq[:N] = queries[order]
+            wa[:N] = arrivals[order]
+            pend = (jnp.asarray(wq), jnp.asarray(wa))
 
         state, qbuf = self._fresh_pool(d)
         warm_pend = pend
-        if ring:
+        if restage:
             # the per-dispatch windows all share this (ring, d) shape,
-            # so one warmup compile covers every dispatch
-            warm_pend = (jnp.zeros((ring, d), jnp.float32),
-                         jnp.full((ring,), NEVER, jnp.int32))
+            # so one warmup compile covers every dispatch (built on the
+            # host: a call staged once compiles no fill of this shape)
+            warm_pend = (jnp.asarray(np.zeros((ring, d), np.float32)),
+                         jnp.asarray(np.full((ring,), NEVER, np.int32)))
         with spans.span(spans.WARMUP, call=phases.call):
             compile_s = self._warmup(state, qbuf, warm_pend)
         watch = _StallWatch()
-        owner = np.full((S, Qs), INVALID, np.int64)   # slot -> qid
-        admit_t = np.zeros((S, Qs), np.int64)
-        admit_wall = np.zeros((S, Qs), np.float64)
-        # live index: serving-age carried across swap restarts (zeroed
-        # at every seat; identically zero without swaps), plus counters
-        age_base = np.zeros((S, Qs), np.int64)
-        rounds_base = np.zeros((S, Qs), np.int64)
+        book = _Book(arrivals, (S, Qs), None if self.live is None
+                     else self.live.map_result)
         epoch_swaps = 0
         swap_stall = 0
         live_del0 = self.live.deletes if self.live is not None else 0
@@ -804,12 +1017,7 @@ class StreamScheduler:
             # pick up direct-API mutations applied since construction
             self.consts.update(self.live.live_consts())
         next_q = 0                                    # cursor into order
-        retired = 0
-        t = 0
-        results: list[QueryResult] = []
-        occ_trace: list[int] = []
-        spec_trace: list[float] = []
-        t0 = time.perf_counter()
+        wall0 = time.perf_counter()
 
         def next_arrival():
             """Earliest arrival round among unadmitted queries (None
@@ -818,305 +1026,216 @@ class StreamScheduler:
                 nas = [arr_by_shard[s, next_qs[s]] for s in range(S)
                        if next_qs[s] < counts[s]]
                 return int(min(nas)) if nas else None
-            if ring:
+            if restage:
                 if staged:
                     return int(arrivals[staged[0]])
                 return (int(arrivals[order[stream_pos]])
                         if stream_pos < N else None)
             return int(arrivals[order[next_q]]) if next_q < N else None
 
-        while retired + len(shed_qids) < N:
-            # an idle-clock jump keeps this dispatch's stage span open
-            phases(spans.STAGE, chunk=dispatches)
-            if self.live is not None and self.live.due(t):
-                # -- live-index boundary: apply every scheduled insert/
-                # delete due by the serving clock; a triggered reindex
-                # (refresh_every, or a full delta) swaps in here — the
-                # one place the pool is between dispatches
-                changed, nswaps = self.live.advance(t)
-                if nswaps:
-                    epoch_swaps += nswaps
-                    state, qbuf, lost = self._swap_epoch(
-                        state, qbuf, owner, age_base, rounds_base)
-                    swap_stall += lost
-                if changed:
-                    self.consts.update(self.live.live_consts())
-            if not injit and routed:
-                # -- host-paced routed admission: each shard fills its
-                # own free rows from its own arrived queue
-                mask = np.zeros((S, Qs), bool)
-                new_q = np.zeros((S, Qs, d), np.float32)
-                now_wall = time.perf_counter()
-                for s in range(S):
-                    free_rows = np.flatnonzero(owner[s] == INVALID)
-                    i = 0
-                    while (i < len(free_rows) and next_qs[s] < counts[s]
-                           and arr_by_shard[s, next_qs[s]] <= t):
-                        qid = int(legidx[s, next_qs[s]])
-                        r = free_rows[i]
-                        mask[s, r] = True
-                        new_q[s, r] = queries[qid]
-                        owner[s, r] = qid
-                        admit_t[s, r] = t
-                        admit_wall[s, r] = now_wall
-                        next_qs[s] += 1
-                        i += 1
-                if mask.any():
-                    state, qbuf = self.stepper.admit(
-                        state, qbuf, jnp.asarray(mask),
-                        jnp.asarray(new_q), *self.entry)
-                    if self.controller is not None:
-                        self.controller.reset_rows(mask)
-            elif not injit:
-                # -- host-paced admission: fill free slots from the
-                # arrived pending queue (the in-jit path seats these
-                # inside the chunk instead)
-                free = np.argwhere(owner == INVALID)
-                pool_all_free = len(free) == S * Qs
-                can_admit = self.refill or pool_all_free
-                staged = []
-                while (can_admit and len(staged) < len(free) and next_q < N
-                       and arrivals[order[next_q]] <= t):
-                    staged.append(order[next_q])
-                    next_q += 1
-                if staged:
+        def pool_idle():
+            """No query holds a row and none has arrived: a chunk
+            launched now runs zero rounds."""
+            na = next_arrival()
+            return ((book.owner == INVALID).all()
+                    and not (na is not None and na <= book.t))
+
+        chunk = None          # launched, not yet accounted
+        while chunk is not None or len(book.results) + len(shed_qids) < N:
+            if chunk is None:
+                # an idle-clock jump keeps this dispatch's stage open
+                phases(spans.STAGE, chunk=dispatches)
+                t = book.t
+                owner = book.owner
+                if self.live is not None and self.live.due(t):
+                    # -- live-index boundary: apply every scheduled
+                    # insert/delete due by the serving clock; a
+                    # triggered reindex (refresh_every, or a full delta)
+                    # swaps in here — the one place the pool is between
+                    # dispatches
+                    changed, nswaps = self.live.advance(t)
+                    if nswaps:
+                        epoch_swaps += nswaps
+                        state, qbuf, lost = self._swap_epoch(
+                            state, qbuf, owner, book.age_base,
+                            book.rounds_base)
+                        swap_stall += lost
+                    if changed:
+                        self.consts.update(self.live.live_consts())
+                if not injit and routed:
+                    # -- host-paced routed admission: each shard fills
+                    # its own free rows from its own arrived queue
                     mask = np.zeros((S, Qs), bool)
                     new_q = np.zeros((S, Qs, d), np.float32)
                     now_wall = time.perf_counter()
-                    for (s, r), qid in zip(free[:len(staged)], staged):
-                        mask[s, r] = True
-                        new_q[s, r] = queries[qid]
-                        owner[s, r] = qid
-                        admit_t[s, r] = t
-                        admit_wall[s, r] = now_wall
-                    state, qbuf = self.stepper.admit(
-                        state, qbuf, jnp.asarray(mask), jnp.asarray(new_q),
-                        *self.entry)
-                    if self.controller is not None:
-                        self.controller.reset_rows(mask)
+                    for s in range(S):
+                        free_rows = np.flatnonzero(owner[s] == INVALID)
+                        i = 0
+                        while (i < len(free_rows)
+                               and next_qs[s] < counts[s]
+                               and arr_by_shard[s, next_qs[s]] <= t):
+                            qid = int(legidx[s, next_qs[s]])
+                            r = free_rows[i]
+                            mask[s, r] = True
+                            new_q[s, r] = queries[qid]
+                            book.seat(s, r, qid, t, now_wall)
+                            next_qs[s] += 1
+                            i += 1
+                    if mask.any():
+                        state, qbuf = self.stepper.admit(
+                            state, qbuf, jnp.asarray(mask),
+                            jnp.asarray(new_q), *self.entry)
+                        if self.controller is not None:
+                            self.controller.reset_rows(mask)
+                elif not injit:
+                    # -- host-paced admission: fill free slots from the
+                    # arrived pending queue (the in-jit path seats these
+                    # inside the chunk instead)
+                    free = np.argwhere(owner == INVALID)
+                    pool_all_free = len(free) == S * Qs
+                    can_admit = self.refill or pool_all_free
+                    staged = []
+                    while (can_admit and len(staged) < len(free)
+                           and next_q < N and arrivals[order[next_q]] <= t):
+                        staged.append(order[next_q])
+                        next_q += 1
+                    if staged:
+                        mask = np.zeros((S, Qs), bool)
+                        new_q = np.zeros((S, Qs, d), np.float32)
+                        now_wall = time.perf_counter()
+                        for (s, r), qid in zip(free[:len(staged)], staged):
+                            mask[s, r] = True
+                            new_q[s, r] = queries[qid]
+                            book.seat(s, r, qid, t, now_wall)
+                        state, qbuf = self.stepper.admit(
+                            state, qbuf, jnp.asarray(mask),
+                            jnp.asarray(new_q), *self.entry)
+                        if self.controller is not None:
+                            self.controller.reset_rows(mask)
 
-            live_mask = owner != INVALID
-            live = int(live_mask.sum())
-            na = next_arrival()
-            arrived_now = na is not None and na <= t
-            if live == 0 and not (injit and arrived_now):
-                # pool idle until the next arrival: jump the serving
-                # clock without a dispatch. The skipped rounds ran no
-                # engine work but they are real serving time — count
-                # them so occupancy/throughput read over the full clock
-                nt = max(t + 1, na) if na is not None else t + 1
-                idle += nt - t
-                t = nt
-                continue
+                live = int((owner != INVALID).sum())
+                na = next_arrival()
+                arrived_now = na is not None and na <= t
+                if live == 0 and not (injit and arrived_now):
+                    # pool idle until the next arrival: jump the serving
+                    # clock without a dispatch. The skipped rounds ran
+                    # no engine work but they are real serving time —
+                    # count them so occupancy/throughput read over the
+                    # full clock
+                    nt = max(t + 1, na) if na is not None else t + 1
+                    idle += nt - t
+                    book.t = nt
+                    continue
 
-            spec_state, cfg, dyn = self._spec_inputs((S, Qs))
-            if injit:
-                # -- device-paced chunk incl. admission: full budget,
-                # no stop-on-finish — freed slots are reseated in-jit
-                # at the exact boundary, and the admit/evict traces let
-                # the host replay the accounting afterwards
-                launch_wall = time.perf_counter()
-                if ring:
-                    # -- bounded ring: slide the window forward (refill
-                    # in arrival order while seats are free), then — if
-                    # shedding — reject every query that has *arrived*
-                    # while the ring is full. Shed decisions are chunk-
-                    # granular: an arrival mid-chunk is judged against
-                    # the ring state at the next boundary.
-                    while len(staged) < ring and stream_pos < N:
-                        staged.append(int(order[stream_pos]))
-                        stream_pos += 1
-                    if self.overload == "shed":
-                        while (len(staged) == ring and stream_pos < N
-                               and arrivals[order[stream_pos]] <= t):
-                            shed_qids.append(int(order[stream_pos]))
+                spec = self._spec_inputs((S, Qs))
+                if injit:
+                    # -- device-paced chunk incl. admission: full
+                    # budget, no stop-on-finish — freed slots are
+                    # reseated in-jit at the exact boundary, and the
+                    # admit/evict traces let the host replay the
+                    # accounting afterwards
+                    if restage:
+                        # -- bounded ring: slide the window forward
+                        # (refill in arrival order while seats are
+                        # free), then — if shedding — reject every query
+                        # that has *arrived* while the ring is full.
+                        # Shed decisions are chunk-granular: an arrival
+                        # mid-chunk is judged against the ring state at
+                        # the next boundary.
+                        while len(staged) < ring and stream_pos < N:
+                            staged.append(int(order[stream_pos]))
                             stream_pos += 1
-                    # restage the window (constant (ring, d) shape, so
-                    # the warmup compile is reused); NEVER-padded tails
-                    # sort after every real arrival for the in-jit
-                    # searchsorted, exactly like the routed padding
-                    win = list(staged)
-                    wq = np.zeros((ring, d), np.float32)
-                    wa = np.full((ring,), NEVER, np.int32)
-                    if win:
-                        wq[:len(win)] = queries[win]
-                        wa[:len(win)] = arrivals[win]
-                    pend = (jnp.asarray(wq), jnp.asarray(wa))
-                    cursor = 0
-                else:
-                    cursor = (jnp.asarray(next_qs, jnp.int32) if routed
-                              else next_q)
-                phases(spans.DISPATCH, chunk=dispatches)
-                (state, qbuf, spec_state, steps, live_cnt, width_sum,
-                 admit_qidx, ret_i, ret_d, ret_rounds, ret_ndist,
-                 ret_age, ret_trunc, cur) = \
-                    self.stepper.run_chunk_admit(
-                        self.consts, state, qbuf, spec_state, cfg, K,
-                        pend, cursor, t, self.entry, dynamic=dyn)
-                phases(spans.SYNC, chunk=dispatches)
-                # the chunk boundary's one sync: everything else below
-                # transfers lazily (and batched) only if needed
-                steps = int(jax.device_get(steps))
-                if self.pagestore is not None and steps:
-                    phases(spans.PAGES, chunk=dispatches)
-                    state = self._page_boundary(state, steps, watch)
-                phases(spans.ACCOUNT, chunk=dispatches)
-                dispatches += 1
-                now_wall = time.perf_counter()
-                admit_qidx = jax.device_get(admit_qidx)[:steps]
-                if admit_qidx.size and (admit_qidx >= 0).any():
-                    # a seat happened: fetch all six eviction-capture
-                    # tensors in a single host transfer
-                    (ret_i, ret_d, ret_rounds, ret_ndist, ret_age,
-                     ret_trunc) = jax.device_get(
-                        (ret_i, ret_d, ret_rounds, ret_ndist, ret_age,
-                         ret_trunc))
-                    for j in range(steps):
-                        for s, r in np.argwhere(admit_qidx[j] >= 0):
-                            if owner[s, r] != INVALID:
-                                # the seated query evicted a finished
-                                # row — emit it from the boundary-j
-                                # capture (bit-identical to a host-side
-                                # retire on that round). retire_round
-                                # advances by age, not rounds: a row
-                                # stalled by a fault aged on the serving
-                                # clock without working
-                                rid = ret_i[j, s, r].copy()
-                                rdd = ret_d[j, s, r].copy()
-                                if self.live is not None:
-                                    rid, rdd = self.live.map_result(
-                                        rid, rdd)
-                                results.append(QueryResult(
-                                    qid=int(owner[s, r]),
-                                    ids=rid, dists=rdd,
-                                    arrival_round=int(
-                                        arrivals[owner[s, r]]),
-                                    admit_round=int(admit_t[s, r]),
-                                    retire_round=int(
-                                        admit_t[s, r] + age_base[s, r]
-                                        + ret_age[j, s, r]),
-                                    service_rounds=int(
-                                        rounds_base[s, r]
-                                        + ret_rounds[j, s, r]),
-                                    n_dist=int(ret_ndist[j, s, r]),
-                                    wall_latency_s=now_wall
-                                    - admit_wall[s, r],
-                                    truncated=bool(
-                                        ret_trunc[j, s, r]),
-                                    stall_rounds=int(
-                                        age_base[s, r]
-                                        + ret_age[j, s, r]
-                                        - rounds_base[s, r]
-                                        - ret_rounds[j, s, r])))
-                                retired += 1
-                            # routed: pidx indexes shard s's own queue;
-                            # ring: pidx indexes this dispatch's window
-                            owner[s, r] = (
-                                int(legidx[s, admit_qidx[j][s, r]])
-                                if routed
-                                else int(win[admit_qidx[j][s, r]])
-                                if ring
-                                else int(order[admit_qidx[j][s, r]]))
-                            admit_t[s, r] = t + j
-                            admit_wall[s, r] = launch_wall
-                            age_base[s, r] = 0
-                            rounds_base[s, r] = 0
-                cur = jax.device_get(cur)
-                if routed:
-                    next_qs = cur.astype(np.int64)
-                elif ring:
-                    del staged[:int(cur)]   # consumed window seats
-                else:
-                    next_q = int(cur)
-            else:
-                # -- host-paced admission needs the chunk to wake
-                # exactly when admission could matter. Free slots ->
-                # nothing can be admitted before the next arrival (the
-                # admission loop above drained everything <= t), so cap
-                # the chunk at that arrival and let mid-chunk finishes
-                # park. Full pool -> a finish may seat a waiting or
-                # imminent arrival, so stop in-jit on the first finish.
-                # Both keep the schedule identical to round_chunk=1.
-                # (frozen mode admits only into an all-free pool, which
-                # the in-jit every-live-row-done exit already detects)
-                budget = K
-                stop_on_finish = False
-                if routed:
-                    # per-shard queues: a freed row only helps a waiting
-                    # leg if it frees on that leg's own shard — a global
-                    # stop-on-finish can't tell, so pace per-round
-                    # (budget 1) while an arrived leg waits and wake
-                    # exactly at the next arrival otherwise
-                    if na is not None:
-                        budget = max(1, min(K, na - t))
-                elif self.refill and na is not None:
-                    if live < S * Qs:
-                        budget = max(1, min(K, na - t))
+                        if self.overload == "shed":
+                            while (len(staged) == ring and stream_pos < N
+                                   and arrivals[order[stream_pos]] <= t):
+                                shed_qids.append(int(order[stream_pos]))
+                                stream_pos += 1
+                        # restage the window (constant (ring, d) shape,
+                        # so the warmup compile is reused)
+                        seat_qid = np.asarray(staged, np.int64)
+                        wq = np.zeros((ring, d), np.float32)
+                        wa = np.full((ring,), NEVER, np.int32)
+                        wq[:len(staged)] = queries[seat_qid]
+                        wa[:len(staged)] = arrivals[seat_qid]
+                        pend = (jnp.asarray(wq), jnp.asarray(wa))
+                        cursor = np.int32(0)
+                    elif routed:
+                        cursor = jnp.asarray(next_qs, jnp.int32)
                     else:
-                        stop_on_finish = na <= t + K
-                phases(spans.DISPATCH, chunk=dispatches)
-                state, spec_state, steps, live_cnt, width_sum = \
-                    self.stepper.run_chunk(self.consts, state, qbuf,
-                                           spec_state, cfg, budget,
-                                           stop_on_finish, dynamic=dyn)
-                phases(spans.SYNC, chunk=dispatches)
-                steps = int(jax.device_get(steps))    # host sync point
-                if self.pagestore is not None and steps:
-                    phases(spans.PAGES, chunk=dispatches)
-                    state = self._page_boundary(state, steps, watch)
-                phases(spans.ACCOUNT, chunk=dispatches)
+                        cursor = np.int32(next_q)
+                    phases(spans.DISPATCH, chunk=dispatches)
+                    chunk = self._dispatch_admit(
+                        dispatches, state, qbuf, spec, pend, cursor,
+                        np.int32(t), seat_qid, ahead)
+                else:
+                    # -- host-paced admission needs the chunk to wake
+                    # exactly when admission could matter. Free slots ->
+                    # nothing can be admitted before the next arrival
+                    # (the admission loop above drained everything
+                    # <= t), so cap the chunk at that arrival and let
+                    # mid-chunk finishes park. Full pool -> a finish may
+                    # seat a waiting or imminent arrival, so stop in-jit
+                    # on the first finish. Both keep the schedule
+                    # identical to round_chunk=1. (frozen mode admits
+                    # only into an all-free pool, which the in-jit
+                    # every-live-row-done exit already detects)
+                    budget = K
+                    stop_on_finish = False
+                    if routed:
+                        # per-shard queues: a freed row only helps a
+                        # waiting leg if it frees on that leg's own
+                        # shard — a global stop-on-finish can't tell, so
+                        # pace per-round (budget 1) while an arrived leg
+                        # waits and wake exactly at the next arrival
+                        # otherwise
+                        if na is not None:
+                            budget = max(1, min(K, na - t))
+                    elif self.refill and na is not None:
+                        if live < S * Qs:
+                            budget = max(1, min(K, na - t))
+                        else:
+                            stop_on_finish = na <= t + K
+                    phases(spans.DISPATCH, chunk=dispatches)
+                    spec_state, cfg, dyn = spec
+                    out = self.stepper.run_chunk(
+                        self.consts, state, qbuf, spec_state, cfg,
+                        budget, stop_on_finish, dynamic=dyn)
+                    chunk = _Chunk(dispatches, None, out[0], qbuf, *out[1:])
                 dispatches += 1
-            t += steps
-            stepped += steps
-            if self.controller is not None:
-                self.controller.store(spec_state)
-            # one batched transfer for the chunk's accounting: the
-            # per-round traces plus the pool state the retire scan reads
-            (live_cnt, width_sum, done, rounds, n_dist, age,
-             trunc) = jax.device_get(
-                (live_cnt, width_sum, state.done, state.rounds,
-                 state.n_dist, state.age, state.truncated))
-            live_cnt = live_cnt[:steps]
-            width_sum = width_sum[:steps]
-            occ_trace.extend(int(c) for c in live_cnt)
-            spec_trace.extend(ws / c for ws, c in
-                              zip(width_sum, np.maximum(live_cnt, 1)))
-
-            # -- retire finished rows (the chunk already parked rows
-            # that hit the per-query round cap, at the exact round
-            # boundary the per-round scheduler would have)
-            fin = (owner != INVALID) & done
-            if fin.any():
-                out_i, out_d, _ = self._retire(state, qbuf)
-                out_i, out_d = jax.device_get((out_i, out_d))
-                now_wall = time.perf_counter()
-                for s, r in np.argwhere(fin):
-                    # exact even when the finish was mid-chunk: the row
-                    # aged `age` consecutive serving rounds from
-                    # admission (== `rounds` worked unless a fault
-                    # stalled it mid-service)
-                    rid = out_i[s, r].copy()
-                    rdd = out_d[s, r].copy()
-                    if self.live is not None:
-                        rid, rdd = self.live.map_result(rid, rdd)
-                    results.append(QueryResult(
-                        qid=int(owner[s, r]), ids=rid, dists=rdd,
-                        arrival_round=int(arrivals[owner[s, r]]),
-                        admit_round=int(admit_t[s, r]),
-                        retire_round=int(admit_t[s, r]
-                                         + age_base[s, r] + age[s, r]),
-                        service_rounds=int(rounds_base[s, r]
-                                           + rounds[s, r]),
-                        n_dist=int(n_dist[s, r]),
-                        wall_latency_s=now_wall - admit_wall[s, r],
-                        truncated=bool(trunc[s, r]),
-                        stall_rounds=int(age_base[s, r] + age[s, r]
-                                         - rounds_base[s, r]
-                                         - rounds[s, r])))
-                    owner[s, r] = INVALID
-                    age_base[s, r] = 0
-                    rounds_base[s, r] = 0
-                retired += int(fin.sum())
+            # -- the chunk boundary. Run ahead: enqueue the next chunk
+            # before this one is synced (resident) or right after its
+            # page boundary (tiered), unless this chunk began with every
+            # query retired or the pool idle: it then runs zero rounds,
+            # and so would the next
+            go = (ahead and len(book.results) < N and not pool_idle())
+            nxt = None
+            if go and self.pagestore is None:
+                nxt = self._enqueue_next(chunk, dispatches, pend, phases)
+                dispatches += 1
+                inflight += 1
+            phases(spans.SYNC, chunk=chunk.cid)
+            # the chunk boundary's one sync: everything else transfers
+            # in one batch in the account
+            steps = int(jax.device_get(chunk.steps))
+            if self.pagestore is not None:
+                phases(spans.PAGES, chunk=chunk.cid)
+                if steps:
+                    chunk.state = self._page_boundary(chunk.state, steps,
+                                                      watch)
+            if go and self.pagestore is not None:
+                nxt = self._enqueue_next(chunk, dispatches, pend, phases)
+                dispatches += 1
+            phases(spans.ACCOUNT, chunk=chunk.cid)
+            empty += steps == 0
+            cur = self._account(book, chunk, steps)
+            if injit and routed:
+                next_qs = cur.astype(np.int64)
+            elif restage:
+                del staged[:int(cur)]   # consumed window seats
+            elif injit:
+                next_q = int(cur)
+            state, qbuf = chunk.state, chunk.qbuf
+            chunk = nxt
 
         phases(spans.FINISH)
         # end-of-session counters: one transfer for the whole summary
@@ -1124,15 +1243,18 @@ class StreamScheduler:
          quarantined) = jax.device_get(
             (state.pages_unique, state.items_recv, state.distance_lanes,
              state.props_sent, state.drops_b, state.quarantined))
+        results = book.results
         return StreamStats(
-            results=results, total_rounds=stepped,
-            occupancy=slot_occupancy(occ_trace, S * Qs, stepped + idle),
-            occupancy_trace=occ_trace,
+            results=results, total_rounds=book.stepped,
+            occupancy=slot_occupancy(book.occ_trace, S * Qs,
+                                     book.stepped + idle),
+            occupancy_trace=book.occ_trace,
             pages_unique=int(pages_unique.sum()),
             items_recv=int(items_recv.sum()),
             props_sent=int(props_sent.sum()),
             drops_b=int(drops_b.sum()),
-            spec_trace=spec_trace, wall_s=time.perf_counter() - t0,
+            spec_trace=book.spec_trace,
+            wall_s=time.perf_counter() - wall0,
             host_dispatches=dispatches, compile_s=compile_s,
             idle_rounds=idle, injit_admit=self.injit_admit,
             items_by_shard=[int(x) for x in np.ravel(items_recv)],
@@ -1153,7 +1275,8 @@ class StreamScheduler:
             tombstoned=(self.live.deletes - live_del0
                         if self.live is not None else 0),
             epoch_swaps=epoch_swaps,
-            swap_stall_rounds=swap_stall)
+            swap_stall_rounds=swap_stall,
+            inflight_dispatches=inflight, empty_dispatches=empty)
 
 
 class _StallWatch:

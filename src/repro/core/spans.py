@@ -15,7 +15,9 @@ transfer, ``PageStore.boundary`` and the consts refresh), and
 ``search.account``, then ``search.finish``. Every span carries the
 call's ``call`` id; the per-dispatch spans also carry ``chunk``, the
 dispatch's index within the call. The ids ride in the event's stats,
-not its name.
+not its name. A call that runs ahead (``StreamScheduler.run``'s
+boundary order) stages and dispatches chunk c+1 before it syncs and
+accounts chunk c, after chunk c's ``search.pages`` where there is one.
 """
 from __future__ import annotations
 

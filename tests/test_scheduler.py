@@ -1,5 +1,7 @@
 """Streaming scheduler == one-shot engine, bit for bit, plus the
 retire/refill slot-reuse and dynamic-speculation machinery."""
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -712,6 +714,63 @@ def test_ring_full_capacity_bit_identity(ds):
     assert ringed.shed == 0
 
 
+def _full_records(st):
+    """Every QueryResult field but the wall clock."""
+    return {r.qid: tuple((f.name, np.asarray(getattr(r, f.name)).tolist())
+                         for f in dataclasses.fields(r)
+                         if f.name != "wall_latency_s")
+            for r in st.results}
+
+
+@pytest.mark.parametrize("layout", ["resident", "ring", "tiered"])
+def test_run_ahead_matches_synchronous_order(ds, layout):
+    """A call whose queue is staged once runs ahead — the host enqueues
+    chunk j+1 from chunk j's device outputs and accounts chunk j after
+    — and serves exactly what the synchronous order serves (a ring
+    shorter than the queue, restaged at every boundary): ids, dists,
+    every QueryResult field but the wall clock, the rounds and the
+    occupancy trace. With a page store only the account moves behind
+    the next launch, after the store's boundary, so no chunk is in
+    flight at a sync. The host learns that the call is done one chunk
+    late: at most one chunk runs zero rounds."""
+    from repro.core.pagestore import PageStore
+
+    db, queries, packed = ds
+    consts, geom, entry = pack_for_engine(packed)
+    sp = SearchParams(L=16, W=1, k=10)
+    nq = queries.shape[0]
+    arrivals = np.random.default_rng(4).integers(0, 15, nq)
+
+    def run(ring):
+        params = EngineParams.lossless(sp, 3, geom.max_degree)
+        kw = {}
+        if layout == "tiered":
+            store = PageStore(consts, geom, 3, w_select=1)
+            params = dataclasses.replace(params,
+                                         store_pages=store.num_pages)
+            kw["pagestore"] = store
+        return stream_search(consts, geom, params, entry, queries,
+                             num_slots=3, arrivals=arrivals, round_chunk=4,
+                             ring_capacity=ring, **kw)
+
+    a_i, a_d, ahead = run(nq if layout == "ring" else 0)
+    s_i, s_d, sync = run(7)
+    np.testing.assert_array_equal(a_i, s_i)
+    np.testing.assert_array_equal(a_d, s_d)
+    assert _full_records(ahead) == _full_records(sync)
+    assert ahead.total_rounds == sync.total_rounds
+    assert ahead.occupancy_trace == sync.occupancy_trace
+    assert sync.inflight_dispatches == 0 and sync.empty_dispatches == 0
+    if layout == "tiered":
+        assert ahead.inflight_dispatches == 0
+        assert ahead.stalls > 0
+    else:
+        assert ahead.inflight_dispatches > 0
+    assert ahead.empty_dispatches <= 1
+    assert (ahead.host_dispatches - ahead.empty_dispatches
+            == sync.host_dispatches)
+
+
 def test_ring_block_property_any_capacity(ds):
     """Hypothesis: under the block policy, any ring capacity >= 1
     serves every query with bit-identical per-query results (admission
@@ -932,11 +991,14 @@ def test_fault_validation(ds):
         StreamScheduler(consts, geom, wrong, entry, num_slots=2)
 
 
-def test_session_compiles_stepper_exactly_once():
+@pytest.mark.parametrize("ring", [0, 8], ids=["run-ahead", "restaged"])
+def test_session_compiles_stepper_exactly_once(ring):
     """Every retire/refill/admit boundary re-dispatches the same jitted
     stepper: a staggered-arrival in-jit session must trigger exactly one
     engine_run_chunk_admit compilation (the warmup), however many chunks
-    the host loop runs."""
+    the host loop runs — whether the cursor and clock arrive as host
+    int32 scalars (a restaged ring) or as the previous chunk's device
+    outputs (a call run ahead). A second call compiles nothing."""
     from repro.analysis.compile_guard import CompileGuard
 
     # Shapes unique to this test: jit caches are process-wide, so
@@ -948,10 +1010,14 @@ def test_session_compiles_stepper_exactly_once():
     params = EngineParams.lossless(sp, 2, geom.max_degree, spec_width=4)
     arrivals = np.random.default_rng(7).integers(0, 12, queries.shape[0])
 
-    with CompileGuard() as cg:
-        ids, dists, st = stream_search(
+    def call():
+        return stream_search(
             consts, geom, params, entry, queries, num_slots=2,
-            arrivals=arrivals, round_chunk=4, injit_admit=True)
+            arrivals=arrivals, round_chunk=4, injit_admit=True,
+            ring_capacity=ring)
+
+    with CompileGuard() as cg:
+        ids, dists, st = call()
 
     n = cg.count("engine_run_chunk_admit")
     assert n == 1, (f"expected exactly the warmup compile, saw {n}: "
@@ -960,6 +1026,10 @@ def test_session_compiles_stepper_exactly_once():
     assert st.host_dispatches > 1
     assert st.total_rounds > 4
     assert len(st.results) == queries.shape[0]
+    assert (st.inflight_dispatches > 0) == (ring == 0)
+    with CompileGuard() as again:
+        call()
+    assert again.total == 0, again.names
 
 
 @pytest.mark.parametrize("kernel_mode,qb", [("ref", 8), ("jnp", 8)])

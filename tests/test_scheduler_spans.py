@@ -1,7 +1,8 @@
 """The served entry's host spans (``core/spans.py``), read back from a
 real profiler trace: one ``search.call`` per call of ``stream_search``,
 tiled by set-up, four spans per round-chunk dispatch (five with a tiered
-page store) and finish, every span carrying its call's id."""
+page store) and finish, every span carrying its call's id; a call that
+runs ahead launches chunk c+1 before it accounts chunk c."""
 import collections
 import dataclasses
 import glob
@@ -65,8 +66,10 @@ def _covered(intervals):
 
 
 @pytest.mark.parametrize("injit,ring,frames", [
-    (True, 8, 0), (False, 0, 0), (True, 8, 2), (False, 0, 2)],
-    ids=["injit-ring", "host-paced", "injit-tiered", "host-paced-tiered"])
+    (True, 8, 0), (False, 0, 0), (True, 8, 2), (False, 0, 2),
+    (True, 0, 0), (True, 0, 2)],
+    ids=["injit-ring", "host-paced", "injit-tiered", "host-paced-tiered",
+         "injit-ahead", "injit-tiered-ahead"])
 def test_spans_tile_each_call(index, tmp_path, injit, ring, frames):
     consts, geom, entry, queries = index
     params = EngineParams.lossless(SearchParams(L=12, W=1, k=5), 2,
@@ -101,17 +104,24 @@ def test_spans_tile_each_call(index, tmp_path, injit, ring, frames):
             chunks = sorted(ids["chunk"] for n, _, _, ids in got
                             if n == name)
             assert chunks == list(range(st.host_dispatches))
+        at = {(n, ids["chunk"]): (s, e) for n, s, e, ids in got
+              if n in PER_DISPATCH + (spans.PAGES,)}
         if frames:
             # the store's boundary lies between the chunk's sync and
             # its account
-            at = {(n, ids["chunk"]): (s, e) for n, s, e, ids in got
-                  if n in (spans.SYNC, spans.PAGES, spans.ACCOUNT)}
             for c in range(st.host_dispatches):
                 assert (at[spans.SYNC, c][1] <= at[spans.PAGES, c][0]
                         and at[spans.PAGES, c][1]
                         <= at[spans.ACCOUNT, c][0])
+            # the store sees chunk c's misses before chunk c+1 launches
+            for c in range(st.host_dispatches - 1):
+                assert at[spans.PAGES, c][1] <= at[spans.DISPATCH, c + 1][0]
         else:
             assert count[spans.PAGES] == 0
+        if injit and not ring and not frames:
+            # run ahead: chunk c+1 launches before chunk c is accounted
+            for c in range(st.host_dispatches - 1):
+                assert at[spans.DISPATCH, c + 1][0] < at[spans.ACCOUNT, c][0]
         first = {name: (s, e) for name, s, e, _ in got}
         c0, c1 = first[spans.CALL]
         assert all(c0 <= s and e <= c1 for _, s, e, _ in got)
